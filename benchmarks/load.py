@@ -304,6 +304,9 @@ def main() -> None:
     ap.add_argument("--out", default=None,
                     help="write the load block to this JSON file")
     args = ap.parse_args()
+    from repro.utils import enable_compilation_cache
+
+    enable_compilation_cache(ROOT)
     block = bench_load(smoke=args.smoke, seed=args.seed)
     print(json.dumps(block, indent=2))
     if args.out:

@@ -711,67 +711,89 @@ def bench_fused_serving(sessions: int = 4, frames: int = 32, res: int = 64,
     }
 
 
+def _sharded_probe(res: int, window: int, sessions: int, frames: int,
+                   devices: int) -> dict:
+    """Render one window batch sharded over ``devices`` and unsharded, in
+    this process, on whatever devices JAX sees; report bit parity."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core import pipeline
+    from repro.core.config import RenderConfig, ShardConfig
+    from repro.core.engine import DeviceSparwEngine
+    from repro.nerf import models, rays, scenes
+
+    scene = scenes.make_scene("lego")
+    model, _ = models.make_model("dvgo", grid_res=32, channels=4,
+                                 decoder="direct", num_samples=16)
+    params = model.init_baked(scene)
+    cam = rays.Camera.square(res)
+    trajs = [pipeline.orbit_trajectory(frames, step_deg=1.0,
+                                       phase_deg=30.0 * i)
+             for i in range(sessions)]
+    ref_poses = jnp.stack([t[0] for t in trajs])
+    tgt_poses = jnp.stack([jnp.stack(t[:window]) for t in trajs])
+
+    def warm_wall(eng, reps=3):
+        r = eng.render_windows(ref_poses, tgt_poses)
+        jax.block_until_ready(r.frames)
+        best = float("inf")
+        for _ in range(reps):
+            t0 = time.time()
+            r = eng.render_windows(ref_poses, tgt_poses)
+            jax.block_until_ready(r.frames)
+            best = min(best, time.time() - t0)
+        return best, r
+
+    cfg = RenderConfig(camera=cam, window=window, num_slots=sessions)
+    base = DeviceSparwEngine(model, params, config=cfg)
+    base_s, r0 = warm_wall(base)
+    sh_cfg = cfg.replace(shard=ShardConfig(num_devices=devices))
+    sh = DeviceSparwEngine(model, params, config=sh_cfg)
+    sh_s, r1 = warm_wall(sh)
+    return dict(
+        devices=jax.device_count(),
+        platform=jax.devices()[0].platform,
+        sessions=sessions,
+        parity_bit_identical=bool(
+            np.array_equal(np.asarray(r0.frames), np.asarray(r1.frames))
+            and np.array_equal(np.asarray(r0.hole_counts),
+                               np.asarray(r1.hole_counts))),
+        warm_wall_s_unsharded=base_s,
+        warm_wall_s_sharded=sh_s,
+        config_fingerprint=sh_cfg.fingerprint(),
+    )
+
+
 def bench_sharded(res: int = 64, window: int = 4, sessions: int = 2,
                   frames: int = 8, devices: int = 2) -> dict:
     """Multi-device session sharding probe: renders the same window batch
-    sharded over ``devices`` forced host devices and unsharded, and gates
-    bit parity. Runs in a subprocess because XLA's device count is fixed
-    at process start. On one physical CPU the two 'devices' share cores,
-    so the recorded walls measure layout overhead, not scaling — the
-    bit-parity gate is the point; real-accelerator scaling is a standing
-    ROADMAP item."""
+    sharded over ``devices`` devices and unsharded, and gates bit parity.
+
+    On an accelerator it runs in this process on the real devices and
+    refuses (``ValueError``) when fewer than ``devices`` are visible: a
+    JAX child would find the chip held by this process. On the CPU it
+    runs in a child with ``devices`` forced host devices, because XLA's
+    device count is fixed at process start; the two 'devices' then share
+    cores, so the recorded walls measure layout overhead, not scaling —
+    the bit-parity gate is the point there."""
     import os
     import subprocess
 
-    code = f"""
-import json, time
-import jax, numpy as np
-import jax.numpy as jnp
-from repro.core import pipeline
-from repro.core.config import RenderConfig, ShardConfig
-from repro.core.engine import DeviceSparwEngine
-from repro.nerf import models, rays, scenes
+    import jax
 
-scene = scenes.make_scene("lego")
-model, _ = models.make_model("dvgo", grid_res=32, channels=4,
-                             decoder="direct", num_samples=16)
-params = model.init_baked(scene)
-cam = rays.Camera.square({res})
-trajs = [pipeline.orbit_trajectory({frames}, step_deg=1.0,
-                                   phase_deg=30.0 * i)
-         for i in range({sessions})]
-ref_poses = jnp.stack([t[0] for t in trajs])
-tgt_poses = jnp.stack([jnp.stack(t[:{window}]) for t in trajs])
-
-def warm_wall(eng, reps=3):
-    r = eng.render_windows(ref_poses, tgt_poses)
-    jax.block_until_ready(r.frames)
-    best = float("inf")
-    for _ in range(reps):
-        t0 = time.time()
-        r = eng.render_windows(ref_poses, tgt_poses)
-        jax.block_until_ready(r.frames)
-        best = min(best, time.time() - t0)
-    return best, r
-
-cfg = RenderConfig(camera=cam, window={window}, num_slots={sessions})
-base = DeviceSparwEngine(model, params, config=cfg)
-base_s, r0 = warm_wall(base)
-sh_cfg = cfg.replace(shard=ShardConfig(num_devices={devices}))
-sh = DeviceSparwEngine(model, params, config=sh_cfg)
-sh_s, r1 = warm_wall(sh)
-print(json.dumps(dict(
-    devices=jax.device_count(),
-    sessions={sessions},
-    parity_bit_identical=bool(
-        np.array_equal(np.asarray(r0.frames), np.asarray(r1.frames))
-        and np.array_equal(np.asarray(r0.hole_counts),
-                           np.asarray(r1.hole_counts))),
-    warm_wall_s_unsharded=base_s,
-    warm_wall_s_sharded=sh_s,
-    config_fingerprint=sh_cfg.fingerprint(),
-)))
-"""
+    if jax.default_backend() != "cpu":
+        if jax.device_count() < devices:
+            raise ValueError(
+                f"bench_sharded needs {devices} devices, "
+                f"{jax.device_count()} visible")
+        block = _sharded_probe(res, window, sessions, frames, devices)
+        block.update(available=True, failed=False)
+        return block
+    code = ("import json; from benchmarks.run import _sharded_probe; "
+            f"print(json.dumps(_sharded_probe({res}, {window}, {sessions}, "
+            f"{frames}, {devices})))")
     env = dict(os.environ,
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
                JAX_PLATFORMS="cpu", PYTHONPATH="src")
@@ -835,6 +857,9 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="substring filter on figure function names")
     args = ap.parse_args()
+    from repro.utils import enable_compilation_cache
+
+    enable_compilation_cache(ROOT)
 
     if args.figures or args.only:
         if run_figures(args.only):

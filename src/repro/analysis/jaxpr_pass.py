@@ -18,10 +18,14 @@ Rules:
                               ``debug_callback``/infeed/outfeed) inside a
                               tick program — a device-to-host sync on the
                               steady path.
-- ``jaxpr-device-put``        explicit ``device_put`` equations or
-                              float64 ``convert_element_type`` on the
-                              steady path (silent placement/precision
-                              traffic the engine contract forbids).
+- ``jaxpr-device-put``        explicit ``device_put`` equations,
+                              non-scalar host arrays captured as program
+                              constants (JAX folds a ``device_put`` of a
+                              host array into them; they are uploaded
+                              with every compile), or float64
+                              ``convert_element_type`` on the steady path
+                              (silent placement/precision traffic the
+                              engine contract forbids).
 - ``jaxpr-dynamic-shape``     every aval in every equation must be a
                               concrete-int ShapedArray — a symbolic or
                               object dim means some input leaks a dynamic
@@ -63,7 +67,7 @@ _HOST_PRIMS = ("callback", "infeed", "outfeed")
 
 
 def _subjaxprs(v) -> Iterable:
-    import jax.core as core
+    from jax.extend import core
 
     vals = v if isinstance(v, (list, tuple)) else [v]
     for x in vals:
@@ -71,6 +75,19 @@ def _subjaxprs(v) -> Iterable:
             yield x.jaxpr
         elif isinstance(x, core.Jaxpr):
             yield x
+
+
+def iter_consts(closed) -> Iterable:
+    """Every constant captured by a closed jaxpr and by the closed
+    sub-jaxprs of its equations (pjit bodies keep their own consts)."""
+    from jax.extend import core
+
+    yield from getattr(closed, "consts", ())
+    for eqn in iter_eqns(closed.jaxpr):
+        for v in eqn.params.values():
+            for x in v if isinstance(v, (list, tuple)) else [v]:
+                if isinstance(x, core.ClosedJaxpr):
+                    yield from x.consts
 
 
 def iter_eqns(jaxpr) -> Iterable:
@@ -91,6 +108,12 @@ def jaxpr_hash(closed) -> str:
 
 def check_program(closed, name: str, path: str, line: int) -> List[Finding]:
     out: List[Finding] = []
+    for c in iter_consts(closed):
+        if not isinstance(c, jax.Array) and jnp.ndim(c) > 0:
+            out.append(Finding(
+                "jaxpr-device-put", path, line, 0,
+                f"{name}: host array of shape {jnp.shape(c)} captured as a "
+                "program constant — it is staged into every compile"))
     for eqn in iter_eqns(closed.jaxpr):
         prim = eqn.primitive.name
         if any(tag in prim for tag in _HOST_PRIMS):

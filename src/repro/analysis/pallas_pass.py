@@ -198,13 +198,13 @@ def repo_launches() -> List[LaunchRecord]:
     # GU gather: [num_mv=4, P=832, C=4] halo table, 2 segments, cap 64
     recs += record_launches(
         gather_trilerp.gather_trilerp_mvoxels_segmented,
-        _sds((4, 832, 4)), _sds((8, 64, 8), jnp.int32), _sds((8, 64, 8)),
+        _sds((4, 832, 4)), _sds((8, 8, 64), jnp.int32), _sds((8, 8, 64)),
         num_seg=2, interpret=True)
     # fused dual-RIT streaming sweep: hole cap 64, reference cap 128
     recs += record_launches(
         streaming_pipeline.fused_gather_dual,
-        _sds((4, 832, 4)), _sds((8, 64, 8), jnp.int32), _sds((8, 64, 8)),
-        _sds((8, 128, 8), jnp.int32), _sds((8, 128, 8)),
+        _sds((4, 832, 4)), _sds((8, 8, 64), jnp.int32), _sds((8, 8, 64)),
+        _sds((8, 8, 128), jnp.int32), _sds((8, 8, 128)),
         num_seg=2, interpret=True)
     # fused NeRF MLP: 1024 samples, width 64, direnc 27, block 512
     h, dd = 64, 27

@@ -149,6 +149,10 @@ class StreamingTickResult(NamedTuple):
     #                           split on the fused path)
     next_rgb_ref: jnp.ndarray  # [S, H, W, 3] — tick t+1's reference frames
     next_dep_ref: jnp.ndarray  # [S, H, W]
+    # [2, 2] int32 — rows (hole stage, reference stage), columns (samples
+    # that spilled past their RIT bucket into the overflow fallback, live
+    # samples gathered); pooled padding rows are not counted
+    rit_counts: jnp.ndarray
 
 
 def render_tick_streaming(model, params: dict, cam: rays.Camera, *,
@@ -158,7 +162,6 @@ def render_tick_streaming(model, params: dict, cam: rays.Camera, *,
                           next_ref_poses: jnp.ndarray,
                           win_lens: jnp.ndarray, caps: jnp.ndarray,
                           pool_caps: jnp.ndarray, bucket: int,
-                          ref_cap_factor: int = 2,
                           dense_fill=None) -> StreamingTickResult:
     """The unified streaming tick: warp → pooled compaction → ONE fused
     Pallas gather serving BOTH the tick's hole fill and the NEXT tick's
@@ -211,24 +214,22 @@ def render_tick_streaming(model, params: dict, cam: rays.Camera, *,
         # mixed-scene slot batch: every segment gathers from its own
         # scene's page of the stacked resident set (traced map — scene
         # churn re-steers this program without recompiling)
-        feats_h, feats_r = streaming_pipeline.gather_features_tick_scenes(
+        feats = streaming_pipeline.gather_features_tick_scenes(
             params["table"], params["mv_table"], scene_of_seg,
             model.streaming_cfg,
             pts_h.reshape(-1, 3), jnp.repeat(hole_batch.seg, ns),
             pts_r.reshape(-1, 3), jnp.repeat(ref_batch.seg, ns),
-            num_seg=s, ref_cap_factor=ref_cap_factor,
-            interpret=c.pallas_interpret)
+            num_seg=s, interpret=c.pallas_interpret)
     else:
-        feats_h, feats_r = streaming_pipeline.gather_features_tick(
+        feats = streaming_pipeline.gather_features_tick(
             params["table"], params["mv_table"], model.streaming_cfg,
             pts_h.reshape(-1, 3), jnp.repeat(hole_batch.seg, ns),
             pts_r.reshape(-1, 3), jnp.repeat(ref_batch.seg, ns),
-            num_seg=s, ref_cap_factor=ref_cap_factor,
-            interpret=c.pallas_interpret)
+            num_seg=s, interpret=c.pallas_interpret)
     sig_h, rgb_h = model.decode_features(
-        params, feats_h, jnp.repeat(hole_batch.dirs, ns, axis=0))
+        params, feats.hole, jnp.repeat(hole_batch.dirs, ns, axis=0))
     sig_r, rgb_r = model.decode_features(
-        params, feats_r, jnp.repeat(ref_batch.dirs, ns, axis=0))
+        params, feats.ref, jnp.repeat(ref_batch.dirs, ns, axis=0))
     fill_col, _, _ = volrend.composite(sig_h.reshape(-1, ns),
                                        rgb_h.reshape(-1, ns, 3), t_h,
                                        c.far, c.white_bkgd)
@@ -239,6 +240,12 @@ def render_tick_streaming(model, params: dict, cam: rays.Camera, *,
     valid = (jnp.arange(bucket)[None, :] < totals[:, None]).reshape(-1)
     sparse = scatter_segments(fill_col, flat_addr, valid,
                               s * n * hw).reshape(s, n, hw, 3)
+    hole_spill = feats.hole_overflow.reshape(-1, ns) & valid[:, None]
+    rit_counts = jnp.stack([
+        jnp.stack([jnp.sum(hole_spill), jnp.sum(valid) * ns]),
+        jnp.stack([jnp.sum(feats.ref_overflow),
+                   jnp.asarray(feats.ref_overflow.size)]),
+    ]).astype(jnp.int32)
     overflowed = frame_over | (totals > pool_caps)
     if dense_fill is not None:
         dense = jax.lax.cond(jnp.any(overflowed),
@@ -252,7 +259,7 @@ def render_tick_streaming(model, params: dict, cam: rays.Camera, *,
     return StreamingTickResult(
         frames.reshape(s, n, h, w, 3), counts.astype(jnp.int32),
         overflowed, counts.astype(jnp.int32),
-        ref_col.reshape(s, h, w, 3), ref_dep.reshape(s, h, w))
+        ref_col.reshape(s, h, w, 3), ref_dep.reshape(s, h, w), rit_counts)
 
 
 def substitute_reference_rows(mask: jnp.ndarray, rgb_new: jnp.ndarray,

@@ -19,6 +19,11 @@ import jax.numpy as jnp
 
 from repro.nerf.rays import Camera
 
+# The rigid transforms run at float32 precision: a TPU's default for a
+# float32 matmul is one bfloat16 pass, whose ~3e-3 relative error moves
+# reprojected pixels and opens spurious holes.
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 class WarpResult(NamedTuple):
     rgb: jnp.ndarray  # [H, W, 3] warped colors (holes = 0)
@@ -43,8 +48,9 @@ def transform_points(points: jnp.ndarray, c2w_ref: jnp.ndarray,
     """Eq. 2: T_{ref->tgt} = w2c_tgt @ c2w_ref applied to ref-frame points."""
     r_ref, t_ref = c2w_ref[:3, :3], c2w_ref[:3, 3]
     r_tgt, t_tgt = c2w_tgt[:3, :3], c2w_tgt[:3, 3]
-    world = points @ r_ref.T + t_ref
-    return (world - t_tgt) @ r_tgt  # R^T x == x @ R
+    world = jnp.matmul(points, r_ref.T, precision=_HIGHEST) + t_ref
+    # R^T x == x @ R
+    return jnp.matmul(world - t_tgt, r_tgt, precision=_HIGHEST)
 
 
 def project(points_tgt: jnp.ndarray, cam: Camera
@@ -76,8 +82,10 @@ def _project_to_target(
     pts_ref = frame_to_pointcloud(depth_ref, cam)
     # world-space points computed once: reused for the Eq. 2 transform below
     # and for the warp-angle heuristic (transform_points would recompute it)
-    world = pts_ref @ c2w_ref[:3, :3].T + c2w_ref[:3, 3]
-    pts_tgt = (world - c2w_tgt[:3, 3]) @ c2w_tgt[:3, :3]  # R^T x == x @ R
+    world = (jnp.matmul(pts_ref, c2w_ref[:3, :3].T, precision=_HIGHEST)
+             + c2w_ref[:3, 3])
+    pts_tgt = jnp.matmul(world - c2w_tgt[:3, 3], c2w_tgt[:3, :3],
+                         precision=_HIGHEST)  # R^T x == x @ R
     u, v, z = project(pts_tgt, cam)
 
     ui = jnp.round(u).astype(jnp.int32)

@@ -20,21 +20,21 @@ from jax.experimental import pallas as pl
 from repro.kernels.common import resolve_interpret
 
 
+def _dot(a, b):
+    # HIGHEST: Mosaic's default for float32 operands is one bfloat16 pass
+    # (~3e-3 relative error against the float32 oracle on a TPU v5e)
+    return jax.lax.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+
+
 def _kernel(x_ref, d_ref, w1_ref, b1_ref, w2_ref, b2_ref, ws_ref, wr_ref,
             br_ref, out_ref):
     x = x_ref[...]  # [blk, C]
-    h = jnp.maximum(jax.lax.dot(x, w1_ref[...],
-                                preferred_element_type=jnp.float32)
-                    + b1_ref[...], 0.0)
-    h = jnp.maximum(jax.lax.dot(h, w2_ref[...],
-                                preferred_element_type=jnp.float32)
-                    + b2_ref[...], 0.0)
-    sigma = jax.nn.softplus(jax.lax.dot(h, ws_ref[...],
-                                        preferred_element_type=jnp.float32))
+    h = jnp.maximum(_dot(x, w1_ref[...]) + b1_ref[...], 0.0)
+    h = jnp.maximum(_dot(h, w2_ref[...]) + b2_ref[...], 0.0)
+    sigma = jax.nn.softplus(_dot(h, ws_ref[...]))
     rgb_in = jnp.concatenate([h, d_ref[...]], axis=-1)
-    rgb = jax.nn.sigmoid(jax.lax.dot(rgb_in, wr_ref[...],
-                                     preferred_element_type=jnp.float32)
-                         + br_ref[...])
+    rgb = jax.nn.sigmoid(_dot(rgb_in, wr_ref[...]) + br_ref[...])
     out_ref[...] = jnp.concatenate([sigma, rgb], axis=-1).astype(out_ref.dtype)
 
 

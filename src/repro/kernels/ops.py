@@ -15,6 +15,7 @@ from repro.core import streaming
 from repro.kernels import flash_attention as _fa
 from repro.kernels import fused_nerf_mlp as _mlp
 from repro.kernels import gather_trilerp as _gt
+from repro.kernels import streaming_pipeline as _sp
 from repro.nerf import grids
 from repro.utils import round_up
 
@@ -63,7 +64,6 @@ def gather_features_streaming(table: jnp.ndarray, points: jnp.ndarray,
         raise ValueError("scene_of_seg requires the seg array (the segment"
                          "→scene map is indexed by segment id)")
     s = points.shape[0]
-    c = table.shape[-1]
     if mv_table is None:
         if scened:
             raise ValueError("mixed-scene gather needs the prebuilt stacked "
@@ -84,11 +84,8 @@ def gather_features_streaming(table: jnp.ndarray, points: jnp.ndarray,
     # match the (possibly bank-interleaved) physical row order of mv_table
     local_ids = streaming.remap_local_ids(local_ids, cfg)
 
-    # per-bucket sample blocks (RIT layout); padded rows use id 0 / weight 0
-    sample_slot = jnp.maximum(rit.samples, 0)  # [num_slots, cap]
-    valid = rit.samples >= 0
-    ids_mv = jnp.where(valid[..., None], local_ids[sample_slot], 0)
-    w_mv = jnp.where(valid[..., None], w[sample_slot], 0.0)
+    # per-bucket sample blocks (RIT layout); padded columns use id 0 / weight 0
+    ids_mv, w_mv = _sp.rit_sample_blocks(local_ids, w, rit.samples)
 
     if scened:
         seg_tables = mv_table[scene_of_seg]  # [num_seg, num_mv, P, C]
@@ -101,21 +98,15 @@ def gather_features_streaming(table: jnp.ndarray, points: jnp.ndarray,
         out_mv = _gt.gather_trilerp_mvoxels(mv_table, ids_mv, w_mv,
                                             interpret=interpret)
 
-    # scatter back to sample order
-    flat_out = out_mv.reshape(-1, c)
-    flat_sample = jnp.where(valid, rit.samples, s).reshape(-1)  # s = dump row
-    feats = jnp.zeros((s + 1, c), table.dtype).at[flat_sample].set(flat_out)
-    feats = feats[:s]
+    feats = _sp.scatter_rit_outputs(out_mv, rit.samples, s)
 
     # overflow fallback (pixel-centric path for the spilled samples)
     gids, gw = grids.corner_ids_weights(points, cfg.grid_res)
     if scened:
-        from repro.kernels import streaming_pipeline as _sp
-
         scn = scene_of_seg[jnp.clip(seg, 0, num_seg - 1)]
         fallback = _sp.gather_trilerp_ref_scened(table, scn, gids, gw)
     else:
-        fallback = grids.gather_trilerp_ref(table, gids, gw)
+        fallback = _sp.fallback_gather(table, gids, gw)
     return jnp.where(rit.overflow[:, None], fallback, feats)
 
 

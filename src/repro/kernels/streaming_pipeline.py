@@ -62,45 +62,46 @@ def fused_gather_dual(mv_table: jnp.ndarray,
     """One MVoxel-table sweep serving BOTH tick stages.
 
     ``ids_h``/``w_h`` are the hole-fill RIT blocks
-    ``[num_seg * num_mv, cap_h, 8]`` and ``ids_r``/``w_r`` the
-    next-reference RIT blocks ``[num_seg * num_mv, cap_r, 8]`` (segment-
-    major, same order as :func:`gather_trilerp_mvoxels_segmented`).
-    Returns ``([num_seg * num_mv, cap_h, C], [num_seg * num_mv, cap_r,
-    C])``. The halo block's BlockSpec depends only on the outer (MVoxel)
+    ``[num_seg * num_mv, 8, cap_h]`` and ``ids_r``/``w_r`` the
+    next-reference RIT blocks ``[num_seg * num_mv, 8, cap_r]`` (segment-
+    major, same order and sample-on-lanes layout as
+    :func:`gather_trilerp_mvoxels_segmented`). Returns ``([num_seg *
+    num_mv, C, cap_h], [num_seg * num_mv, C, cap_r])``. The halo block's BlockSpec depends only on the outer (MVoxel)
     grid index, so the pipeline fetches it once per MVoxel and both
     stages' gathers run against the resident copy.
     """
     interpret = resolve_interpret(interpret)
     num_mv, p, c = mv_table.shape
-    cap_h, cap_r = ids_h.shape[1], ids_r.shape[1]
-    ih4 = ids_h.reshape(num_seg, num_mv, cap_h, 8)
-    wh4 = w_h.reshape(num_seg, num_mv, cap_h, 8)
-    ir4 = ids_r.reshape(num_seg, num_mv, cap_r, 8)
-    wr4 = w_r.reshape(num_seg, num_mv, cap_r, 8)
+    cap_h, cap_r = ids_h.shape[2], ids_r.shape[2]
+    ih4 = ids_h.reshape(num_seg, num_mv, 8, cap_h)
+    wh4 = w_h.reshape(num_seg, num_mv, 8, cap_h)
+    ir4 = ids_r.reshape(num_seg, num_mv, 8, cap_r)
+    wr4 = w_r.reshape(num_seg, num_mv, 8, cap_r)
     out_h, out_r = pl.pallas_call(
         _fused_kernel,
         grid=(num_mv, num_seg),  # seg innermost: halo block stays resident
         in_specs=[
             pl.BlockSpec((1, p, c), lambda m, s: (m, 0, 0)),
-            pl.BlockSpec((1, 1, cap_h, 8), lambda m, s: (s, m, 0, 0)),
-            pl.BlockSpec((1, 1, cap_h, 8), lambda m, s: (s, m, 0, 0)),
-            pl.BlockSpec((1, 1, cap_r, 8), lambda m, s: (s, m, 0, 0)),
-            pl.BlockSpec((1, 1, cap_r, 8), lambda m, s: (s, m, 0, 0)),
+            pl.BlockSpec((1, 1, 8, cap_h), lambda m, s: (s, m, 0, 0)),
+            pl.BlockSpec((1, 1, 8, cap_h), lambda m, s: (s, m, 0, 0)),
+            pl.BlockSpec((1, 1, 8, cap_r), lambda m, s: (s, m, 0, 0)),
+            pl.BlockSpec((1, 1, 8, cap_r), lambda m, s: (s, m, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, cap_h, c), lambda m, s: (s, m, 0, 0)),
-            pl.BlockSpec((1, 1, cap_r, c), lambda m, s: (s, m, 0, 0)),
+            pl.BlockSpec((1, 1, c, cap_h), lambda m, s: (s, m, 0, 0)),
+            pl.BlockSpec((1, 1, c, cap_r), lambda m, s: (s, m, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((num_seg, num_mv, cap_h, c),
+            jax.ShapeDtypeStruct((num_seg, num_mv, c, cap_h),
                                  mv_table.dtype),
-            jax.ShapeDtypeStruct((num_seg, num_mv, cap_r, c),
+            jax.ShapeDtypeStruct((num_seg, num_mv, c, cap_r),
                                  mv_table.dtype),
         ],
+        compiler_params=_gt.COMPILER_PARAMS,
         interpret=interpret,
     )(mv_table, ih4, wh4, ir4, wr4)
-    return (out_h.reshape(num_seg * num_mv, cap_h, c),
-            out_r.reshape(num_seg * num_mv, cap_r, c))
+    return (out_h.reshape(num_seg * num_mv, c, cap_h),
+            out_r.reshape(num_seg * num_mv, c, cap_r))
 
 
 def _fused_kernel_per_seg(tbl_ref, ih_ref, wh_ref, ir_ref, wr_ref,
@@ -130,59 +131,80 @@ def fused_gather_dual_per_seg(mv_tables: jnp.ndarray,
     the tick still fetches each *distinct* resident block once."""
     interpret = resolve_interpret(interpret)
     _, num_mv, p, c = mv_tables.shape
-    cap_h, cap_r = ids_h.shape[1], ids_r.shape[1]
-    ih4 = ids_h.reshape(num_seg, num_mv, cap_h, 8)
-    wh4 = w_h.reshape(num_seg, num_mv, cap_h, 8)
-    ir4 = ids_r.reshape(num_seg, num_mv, cap_r, 8)
-    wr4 = w_r.reshape(num_seg, num_mv, cap_r, 8)
+    cap_h, cap_r = ids_h.shape[2], ids_r.shape[2]
+    ih4 = ids_h.reshape(num_seg, num_mv, 8, cap_h)
+    wh4 = w_h.reshape(num_seg, num_mv, 8, cap_h)
+    ir4 = ids_r.reshape(num_seg, num_mv, 8, cap_r)
+    wr4 = w_r.reshape(num_seg, num_mv, 8, cap_r)
     out_h, out_r = pl.pallas_call(
         _fused_kernel_per_seg,
         grid=(num_mv, num_seg),  # seg innermost: scene-adjacent block reuse
         in_specs=[
             pl.BlockSpec((1, 1, p, c), lambda m, s: (s, m, 0, 0)),
-            pl.BlockSpec((1, 1, cap_h, 8), lambda m, s: (s, m, 0, 0)),
-            pl.BlockSpec((1, 1, cap_h, 8), lambda m, s: (s, m, 0, 0)),
-            pl.BlockSpec((1, 1, cap_r, 8), lambda m, s: (s, m, 0, 0)),
-            pl.BlockSpec((1, 1, cap_r, 8), lambda m, s: (s, m, 0, 0)),
+            pl.BlockSpec((1, 1, 8, cap_h), lambda m, s: (s, m, 0, 0)),
+            pl.BlockSpec((1, 1, 8, cap_h), lambda m, s: (s, m, 0, 0)),
+            pl.BlockSpec((1, 1, 8, cap_r), lambda m, s: (s, m, 0, 0)),
+            pl.BlockSpec((1, 1, 8, cap_r), lambda m, s: (s, m, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, cap_h, c), lambda m, s: (s, m, 0, 0)),
-            pl.BlockSpec((1, 1, cap_r, c), lambda m, s: (s, m, 0, 0)),
+            pl.BlockSpec((1, 1, c, cap_h), lambda m, s: (s, m, 0, 0)),
+            pl.BlockSpec((1, 1, c, cap_r), lambda m, s: (s, m, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((num_seg, num_mv, cap_h, c),
+            jax.ShapeDtypeStruct((num_seg, num_mv, c, cap_h),
                                  mv_tables.dtype),
-            jax.ShapeDtypeStruct((num_seg, num_mv, cap_r, c),
+            jax.ShapeDtypeStruct((num_seg, num_mv, c, cap_r),
                                  mv_tables.dtype),
         ],
+        compiler_params=_gt.COMPILER_PARAMS,
         interpret=interpret,
     )(mv_tables, ih4, wh4, ir4, wr4)
-    return (out_h.reshape(num_seg * num_mv, cap_h, c),
-            out_r.reshape(num_seg * num_mv, cap_r, c))
+    return (out_h.reshape(num_seg * num_mv, c, cap_h),
+            out_r.reshape(num_seg * num_mv, c, cap_r))
 
 
 class _RitBlocks(NamedTuple):
-    ids_mv: jnp.ndarray   # [num_slots, cap, 8] — layout-remapped local ids
-    w_mv: jnp.ndarray     # [num_slots, cap, 8]
+    ids_mv: jnp.ndarray   # [num_slots, 8, cap] — layout-remapped local ids
+    w_mv: jnp.ndarray     # [num_slots, 8, cap]
     samples: jnp.ndarray  # [num_slots, cap] sample ids (-1 pad)
     overflow: jnp.ndarray  # [T] bool
+
+
+def rit_sample_blocks(local_ids: jnp.ndarray, w: jnp.ndarray,
+                      samples: jnp.ndarray
+                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Per-sample corner ids/weights ``[T, 8]`` → RIT-order kernel blocks
+    ``[num_slots, 8, cap]`` (sample axis on lanes; pad columns: id 0,
+    weight 0). ``samples`` is the RIT's ``[num_slots, cap]`` sample ids."""
+    sample_slot = jnp.maximum(samples, 0)
+    valid = (samples >= 0)[:, None, :]
+    ids_mv = jnp.where(valid, jnp.swapaxes(local_ids[sample_slot], 1, 2), 0)
+    w_mv = jnp.where(valid, jnp.swapaxes(w[sample_slot], 1, 2), 0.0)
+    return ids_mv, w_mv
+
+
+def scatter_rit_outputs(out_mv: jnp.ndarray, samples: jnp.ndarray,
+                        t: int) -> jnp.ndarray:
+    """RIT-order kernel output ``[num_slots, C, cap]`` back to sample
+    order ``[t, C]``; samples the RIT did not hold stay zero."""
+    c = out_mv.shape[1]
+    flat_sample = jnp.where(samples >= 0, samples, t).reshape(-1)
+    rows = jnp.swapaxes(out_mv, 1, 2).reshape(-1, c)
+    return jnp.zeros((t + 1, c), out_mv.dtype).at[flat_sample].set(rows)[:t]
 
 
 def _rit_blocks(points: jnp.ndarray, seg: jnp.ndarray, num_seg: int,
                 cfg: streaming.StreamingCfg) -> _RitBlocks:
     """Bucket one sample set per (segment, MVoxel) and lay its corner
     ids/weights out in RIT order for the fused kernel (``cfg.capacity``
-    rows per bucket; padding seg ids >= num_seg drop out)."""
+    samples per bucket; padding seg ids >= num_seg drop out)."""
     num_mv = cfg.num_mvoxels
     mv = streaming.mvoxel_ids(points, cfg)
     bucket = jnp.where(seg < num_seg, seg * num_mv + mv, num_seg * num_mv)
     rit = streaming.build_rit(bucket, cfg, num_slots=num_seg * num_mv)
     local_ids, w = streaming.local_corner_ids(points, cfg)
     local_ids = streaming.remap_local_ids(local_ids, cfg)
-    sample_slot = jnp.maximum(rit.samples, 0)
-    valid = rit.samples >= 0
-    ids_mv = jnp.where(valid[..., None], local_ids[sample_slot], 0)
-    w_mv = jnp.where(valid[..., None], w[sample_slot], 0.0)
+    ids_mv, w_mv = rit_sample_blocks(local_ids, w, rit.samples)
     return _RitBlocks(ids_mv, w_mv, rit.samples, rit.overflow)
 
 
@@ -192,28 +214,29 @@ def _scatter_with_fallback(out_mv: jnp.ndarray, blocks: _RitBlocks,
     """RIT-order kernel output back to sample order; RIT-overflow samples
     take the reference (pixel-centric) gather on the ORIGINAL table — the
     paper's fallback, layout-independent by construction."""
-    t = points.shape[0]
-    c = out_mv.shape[-1]
-    valid = blocks.samples >= 0
-    flat_sample = jnp.where(valid, blocks.samples, t).reshape(-1)
-    feats = jnp.zeros((t + 1, c), table.dtype).at[flat_sample].set(
-        out_mv.reshape(-1, c))
-    feats = feats[:t]
+    feats = scatter_rit_outputs(out_mv, blocks.samples, points.shape[0])
     gids, gw = grids.corner_ids_weights(points, cfg.grid_res)
-    fallback = grids.gather_trilerp_ref(table, gids, gw)
+    fallback = fallback_gather(table, gids, gw)
     return jnp.where(blocks.overflow[:, None], fallback, feats)
 
 
 def gather_trilerp_ref_scened(tables: jnp.ndarray, scene: jnp.ndarray,
                               ids: jnp.ndarray, weights: jnp.ndarray
                               ) -> jnp.ndarray:
-    """Per-sample-scene reference gather over stacked dense tables
-    ``[K, res^3, C]``: the same rows and the same einsum as
-    ``grids.gather_trilerp_ref`` on the sample's own scene's table, so a
+    """Per-sample-scene fallback gather over stacked dense tables
+    ``[K, res^3, C]``: the same rows and the same corner sum as
+    :func:`fallback_gather` on the sample's own scene's table, so a
     single-scene slice of the output is bit-identical to the exclusive
-    reference gather."""
-    feats = tables[scene[:, None], ids]  # [S, 8, C]
-    return jnp.einsum("svc,sv->sc", feats, weights)
+    fallback gather."""
+    return grids.gather_trilerp_corners(lambda v: tables[scene, ids[:, v]],
+                                        weights)
+
+
+def fallback_gather(table: jnp.ndarray, ids: jnp.ndarray,
+                    weights: jnp.ndarray) -> jnp.ndarray:
+    """The RIT-overflow fallback: the pixel-centric gather on the ORIGINAL
+    dense table ``[res^3, C]``, computed for every sample of the stage."""
+    return grids.gather_trilerp_corners(lambda v: table[ids[:, v]], weights)
 
 
 def _scatter_with_fallback_scened(out_mv: jnp.ndarray, blocks: _RitBlocks,
@@ -222,16 +245,20 @@ def _scatter_with_fallback_scened(out_mv: jnp.ndarray, blocks: _RitBlocks,
                                   cfg: streaming.StreamingCfg) -> jnp.ndarray:
     """Mixed-scene :func:`_scatter_with_fallback`: the overflow fallback
     reads each sample's own scene's ORIGINAL dense table."""
-    t = points.shape[0]
-    c = out_mv.shape[-1]
-    valid = blocks.samples >= 0
-    flat_sample = jnp.where(valid, blocks.samples, t).reshape(-1)
-    feats = jnp.zeros((t + 1, c), tables.dtype).at[flat_sample].set(
-        out_mv.reshape(-1, c))
-    feats = feats[:t]
+    feats = scatter_rit_outputs(out_mv, blocks.samples, points.shape[0])
     gids, gw = grids.corner_ids_weights(points, cfg.grid_res)
     fallback = gather_trilerp_ref_scened(tables, scene, gids, gw)
     return jnp.where(blocks.overflow[:, None], fallback, feats)
+
+
+class TickFeatures(NamedTuple):
+    """The fused sweep's gathered features in sample order, plus which
+    samples spilled past their RIT bucket and took the overflow fallback."""
+
+    hole: jnp.ndarray           # [Th, C]
+    ref: jnp.ndarray            # [Tr, C]
+    hole_overflow: jnp.ndarray  # [Th] bool
+    ref_overflow: jnp.ndarray   # [Tr] bool
 
 
 def gather_features_tick_scenes(tables: jnp.ndarray, mv_tables: jnp.ndarray,
@@ -241,7 +268,7 @@ def gather_features_tick_scenes(tables: jnp.ndarray, mv_tables: jnp.ndarray,
                                 pts_ref: jnp.ndarray, seg_ref: jnp.ndarray, *,
                                 num_seg: int, ref_cap_factor: int = 2,
                                 interpret: bool | None = None
-                                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                                ) -> TickFeatures:
     """Mixed-scene :func:`gather_features_tick`: one fused sweep over the
     *resident scene set*.
 
@@ -267,7 +294,7 @@ def gather_features_tick_scenes(tables: jnp.ndarray, mv_tables: jnp.ndarray,
                                             pts_hole, cfg)
     feats_r = _scatter_with_fallback_scened(out_r, br, tables, scn_r,
                                             pts_ref, cfg)
-    return feats_h, feats_r
+    return TickFeatures(feats_h, feats_r, bh.overflow, br.overflow)
 
 
 def gather_features_tick(table: jnp.ndarray, mv_table: jnp.ndarray,
@@ -276,7 +303,7 @@ def gather_features_tick(table: jnp.ndarray, mv_table: jnp.ndarray,
                          pts_ref: jnp.ndarray, seg_ref: jnp.ndarray, *,
                          num_seg: int, ref_cap_factor: int = 2,
                          interpret: bool | None = None
-                         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                         ) -> TickFeatures:
     """The tick's ONE feature-gather pass: hole-fill + next-reference
     samples through a single fused MVoxel-table sweep.
 
@@ -285,8 +312,9 @@ def gather_features_tick(table: jnp.ndarray, mv_table: jnp.ndarray,
     tick's reference samples. The reference set is the denser stream (a
     full frame per session vs. a hole pool), so its RIT capacity scales
     by ``ref_cap_factor`` to keep the overflow-fallback rate comparable
-    to the staged path's per-chunk RITs. Returns (hole features
-    ``[Th, C]``, reference features ``[Tr, C]``) in sample order.
+    to the staged path's per-chunk RITs. Returns the hole features
+    ``[Th, C]`` and reference features ``[Tr, C]`` in sample order, with
+    each set's per-sample RIT-overflow mask (:class:`TickFeatures`).
     """
     cfg_ref = dataclasses.replace(
         cfg, capacity=cfg.capacity * ref_cap_factor)
@@ -297,7 +325,7 @@ def gather_features_tick(table: jnp.ndarray, mv_table: jnp.ndarray,
                                      interpret=interpret)
     feats_h = _scatter_with_fallback(out_h, bh, table, pts_hole, cfg)
     feats_r = _scatter_with_fallback(out_r, br, table, pts_ref, cfg)
-    return feats_h, feats_r
+    return TickFeatures(feats_h, feats_r, bh.overflow, br.overflow)
 
 
 # ---------------------------------------------------------------------------

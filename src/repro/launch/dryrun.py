@@ -3,6 +3,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 # NOTE: the two lines above MUST run before any jax import — jax locks the
 # device count on first init. (This also means: no `from __future__` here.)
+# CPU-only tool: importing this module overwrites XLA_FLAGS, so no program
+# that runs on an accelerator may import it.
 
 _DOC = """Multi-pod dry-run: .lower().compile() every (arch × shape × mesh) cell.
 
@@ -287,7 +289,9 @@ def default_out(arch, shape_name, mesh_name, tag="") -> Path:
 
 def run_all(mesh_names, jobs: int = 1, include_nerf: bool = True,
             skip_existing: bool = True) -> None:
-    """Drive every cell in a subprocess (isolation: one bad cell ≠ dead run)."""
+    """Drive every cell in a subprocess (isolation: one bad cell ≠ dead run).
+    CPU-only: each child is a JAX process, which on an accelerator host
+    would contend with its parent for the chip."""
     cells = []
     for mesh_name in mesh_names:
         for arch, shape_name in registry.runnable_cells():

@@ -105,17 +105,8 @@ def guard_spec(spec: P, shape, mesh, strict: bool = False) -> P:
 
 
 def current_abstract_mesh():
-    """The mesh in context, as an AbstractMesh (``.empty`` when none).
-
-    ``jax.sharding.get_abstract_mesh`` where it exists (jax >= 0.5);
-    otherwise derived from the thread-resources physical mesh that the
-    ``with mesh:`` context manager sets (jax 0.4.x)."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        return get()
-    from jax._src import mesh as _mesh_lib
-
-    return _mesh_lib.thread_resources.env.physical_mesh.abstract_mesh
+    """The mesh in context, as an AbstractMesh (``.empty`` when none)."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def shard(x: jnp.ndarray, spec: P) -> jnp.ndarray:

@@ -24,7 +24,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.models import common
 from repro.models.common import DP, TP, ninit, shard
-from repro.utils import shard_map_compat
 
 
 def moe_init(key, cfg: ModelConfig, dtype) -> dict:
@@ -149,7 +148,7 @@ def _dispatch_combine(x, idx, gate, cfg, slot_of_pair, keep, params):
                                   gate_l, e_loc)
             return jax.lax.psum(part.astype(x_l.dtype), "model")
 
-        out = shard_map_compat(
+        out = jax.shard_map(
             local_moe, mesh=mesh,
             in_specs=(P(dp, None, None), P(dp, None), P(dp, None),
                       P(dp, None), P(dp, None),

@@ -97,7 +97,9 @@ def generate_rays(cam: Camera, c2w: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarr
     Returns (origins [H*W, 3], directions [H*W, 3]); directions are unit-norm.
     Row-major pixel order — the *pixel-centric* order the paper starts from.
     """
-    dirs_world = camera_dirs_device(cam) @ c2w[:3, :3].T
+    # float32 precision: a TPU's default matmul is one bfloat16 pass
+    dirs_world = jnp.matmul(camera_dirs_device(cam), c2w[:3, :3].T,
+                            precision=jax.lax.Precision.HIGHEST)
     dirs_world = dirs_world / jnp.linalg.norm(dirs_world, axis=-1, keepdims=True)
     origins = jnp.broadcast_to(c2w[:3, 3], dirs_world.shape)
     return origins, dirs_world

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 
 
@@ -33,8 +34,12 @@ def composite(
     trans = jnp.concatenate([jnp.ones_like(trans[:, :1]), trans[:, :-1]], axis=-1)
     weights = trans * alpha  # [R, N]
     acc = weights.sum(axis=-1)  # [R]
-    color = jnp.einsum("rn,rnc->rc", weights, rgbs)
-    depth = jnp.einsum("rn,rn->r", weights, t_vals) + (1.0 - acc) * far
+    # float32 precision: a TPU's default matmul is one bfloat16 pass, and
+    # the depth feeds the warp's reprojection
+    hi = jax.lax.Precision.HIGHEST
+    color = jnp.einsum("rn,rnc->rc", weights, rgbs, precision=hi)
+    depth = (jnp.einsum("rn,rn->r", weights, t_vals, precision=hi)
+             + (1.0 - acc) * far)
     if white_bkgd:
         color = color + (1.0 - acc)[:, None]
     return color, depth, weights

@@ -13,7 +13,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def _partial_attn(q, k, v, valid, sm_scale):
@@ -53,11 +52,11 @@ def sharded_decode_attention(q, k_cache, v_cache, index, *, mesh,
         osum = jax.lax.psum(o * w, seq_axis)
         return (osum / jnp.maximum(lsum, 1e-30)).astype(q.dtype)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(None, None, seq_axis, None),
                   P(None, None, seq_axis, None), P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     o = fn(qg, k_cache, v_cache, index)
     return o.reshape(b, 1, h * d)

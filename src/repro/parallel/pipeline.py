@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.utils import shard_map_compat
 
 PyTree = Any
 
@@ -87,7 +86,7 @@ def pipelined_forward(layer_fn: Callable, params_stacked: PyTree,
         return outs.reshape(b, *x_all.shape[1:])
 
     other_axes = tuple(a for a in mesh.axis_names if a != axis)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         pipeline, mesh=mesh,
         in_specs=(P(axis), P()),  # layers over pods; batch replicated
         out_specs=P(),

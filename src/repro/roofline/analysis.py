@@ -197,12 +197,8 @@ def model_flops(cfg, shape) -> float:
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a dict across jax versions (0.4.x
-    returns a one-element list of dicts, newer jax the dict itself)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    """``compiled.cost_analysis()``: XLA's flops/bytes estimate as a dict."""
+    return compiled.cost_analysis()
 
 
 def from_compiled(arch: str, shape_name: str, mesh_name: str, num_devices: int,

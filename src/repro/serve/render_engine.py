@@ -286,6 +286,9 @@ class RenderServeEngine:
         self._rgb_ref: Optional[jnp.ndarray] = None
         self._dep_ref: Optional[jnp.ndarray] = None
         self._num_admission_ticks = 0  # ticks that ran a prime dispatch
+        # fused ticks' RIT spill: [stage (hole, ref), (spilled, gathered)]
+        # sample totals over every finalized tick (engine lifetime)
+        self._rit_counts = np.zeros((2, 2), np.int64)
 
     # ------------------------------------------------------------------
     def _effective(self, sess: RenderSession) -> Tuple[int, int]:
@@ -664,6 +667,8 @@ class RenderServeEngine:
         split = max(len(self._pending) - keep, 0)
         done, self._pending = self._pending[:split], self._pending[split:]
         for assignments, res, (bf, bc) in done:
+            if self.fused:
+                self._rit_counts += np.asarray(res.rit_counts)
             counts = np.asarray(res.hole_counts)
             fine = np.asarray(res.fine_counts)
             overflowed = np.asarray(res.overflowed)
@@ -731,6 +736,7 @@ class RenderServeEngine:
         # admission count) across runs, so report the deltas
         buckets_start = len(self.engine.pool_buckets_used)
         adm_start = self._num_admission_ticks
+        rit_start = self._rit_counts.copy()
         # same per-run-delta convention for queue/occupancy/scene-cache
         qd_start = len(self._queue_depth_log)
         shed_start = self._num_shed
@@ -858,6 +864,17 @@ class RenderServeEngine:
                     ticks_run, adm_ticks,
                     memory_metrics["staged_ref_sweeps"]) if fused
                 else memory_metrics["staged_table_sweeps_per_tick"])
+        # fused ticks' RIT overflow per stage: the share of live gather
+        # samples that spilled past their (segment, MVoxel) bucket and took
+        # the XLA fallback gather instead of the streaming kernel
+        rit_metrics = None
+        if self.fused:
+            rit = self._rit_counts - rit_start
+            rit_metrics = {
+                stage: {"spilled_samples": int(rit[i, 0]),
+                        "samples": int(rit[i, 1]),
+                        "overflow_share": float(rit[i, 0] / max(rit[i, 1], 1))}
+                for i, stage in enumerate(("hole", "ref"))}
         return {
             "ticks": self.num_ticks - start_ticks,
             "wall_s": wall_s,
@@ -868,6 +885,7 @@ class RenderServeEngine:
             "policy": self.policy.name,
             "pool": pool_metrics,
             "memory": memory_metrics,
+            "rit_overflow": rit_metrics,
             "queue": queue_metrics,
             "slots": slot_metrics,
             "scene_cache": scene_metrics,

@@ -2,6 +2,8 @@
 from __future__ import annotations
 
 import functools
+import os
+from pathlib import Path
 from typing import Any, Callable, Iterable
 
 import jax
@@ -79,25 +81,21 @@ def chunked(seq, size):
         yield seq[i : i + size]
 
 
-def shard_map_compat(fn: Callable, *, mesh, in_specs, out_specs,
-                     axis_names=None, check_vma: bool = False) -> Callable:
-    """``jax.shard_map`` across jax versions.
+def enable_compilation_cache(checkout: Path) -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
 
-    jax >= 0.6 exposes ``jax.shard_map(..., axis_names=..., check_vma=...)``;
-    0.4.x has ``jax.experimental.shard_map.shard_map(..., auto=...,
-    check_rep=...)``. ``axis_names`` (the manual axes) maps to the old
-    ``auto`` as its complement over the mesh axes."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {"axis_names": axis_names} if axis_names is not None else {}
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma, **kw)
-    from jax.experimental.shard_map import shard_map as sm_old
-
-    auto = (frozenset(mesh.axis_names) - frozenset(axis_names)
-            if axis_names is not None else frozenset())
-    return sm_old(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma, auto=auto)
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is honored as JAX reads it and
+    nothing is set in code. Otherwise the cache lives at the fixed
+    ``<checkout>/.jax_cache`` (gitignored): the directory is part of the
+    cache key, so it must not move between runs. Call it from a program's
+    ``main()``, never at import.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(Path(checkout).resolve() / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def jit_with_name(fn: Callable, name: str, **jit_kwargs) -> Callable:

@@ -219,13 +219,26 @@ def test_rule_jaxpr_host_transfer_fires_on_callback():
     assert hits and hits[0].line == 7 and "leaky" in hits[0].message
 
 
-def test_rule_jaxpr_device_put_fires():
+@pytest.mark.parametrize("operand", ["host_constant", "traced"])
+def test_rule_jaxpr_device_put_fires(operand):
     def puts(x):
-        return x + jax.device_put(np.ones(3, np.float32))
+        # a host array is folded into the program's constants; a traced
+        # operand leaves a device_put equation
+        if operand == "host_constant":
+            return x + jax.device_put(np.ones(3, np.float32))
+        return x + jax.device_put(x * 2.0)
 
     closed = jax.make_jaxpr(puts)(jnp.ones(3))
     assert _only(jaxpr_pass.check_program(closed, "puts", "p.py", 1),
                  "jaxpr-device-put")
+
+
+def test_rule_jaxpr_device_put_ignores_device_constants():
+    table = jnp.ones(3)
+
+    closed = jax.make_jaxpr(lambda x: x * table + 2.0)(jnp.ones(3))
+    assert not _only(jaxpr_pass.check_program(closed, "ok", "p.py", 1),
+                     "jaxpr-device-put")
 
 
 def test_rule_jaxpr_dynamic_shape_fires_on_symbolic_dim():
@@ -323,8 +336,8 @@ def test_pallas_spy_captures_real_kernel_geometry():
     recs = pallas_pass.record_launches(
         gather_trilerp.gather_trilerp_mvoxels_segmented,
         jax.ShapeDtypeStruct((4, 832, 4), jnp.float32),
-        jax.ShapeDtypeStruct((8, 64, 8), jnp.int32),
-        jax.ShapeDtypeStruct((8, 64, 8), jnp.float32),
+        jax.ShapeDtypeStruct((8, 8, 64), jnp.int32),
+        jax.ShapeDtypeStruct((8, 8, 64), jnp.float32),
         num_seg=2, interpret=True)
     assert len(recs) == 1
     rec = recs[0]

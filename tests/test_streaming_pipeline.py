@@ -96,7 +96,7 @@ def test_fused_gather_matches_reference_both_sets(table, pts):
     ref = np.asarray(grids.gather_trilerp_ref(table, ids, w))
     for cfg in (CFG_I, CFG_B):
         mv = streaming.build_mvoxel_table(table, cfg)
-        fh, fr = streaming_pipeline.gather_features_tick(
+        fh, fr, _, _ = streaming_pipeline.gather_features_tick(
             table, mv, cfg, pts, seg, pts, seg, num_seg=2, interpret=True)
         np.testing.assert_allclose(np.asarray(fh), ref, atol=1e-5,
                                    rtol=1e-5)
@@ -126,11 +126,13 @@ def test_fused_gather_ref_set_capacity_scales(table, pts):
     seg = jnp.zeros(pts.shape[0], jnp.int32)
     ids, w = grids.corner_ids_weights(pts, small.grid_res)
     ref = np.asarray(grids.gather_trilerp_ref(table, ids, w))
-    fh, fr = streaming_pipeline.gather_features_tick(
+    fh, fr, spill_h, spill_r = streaming_pipeline.gather_features_tick(
         table, mv, small, pts, seg, pts, seg, num_seg=1, ref_cap_factor=4,
         interpret=True)
     np.testing.assert_allclose(np.asarray(fh), ref, atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(np.asarray(fr), ref, atol=1e-5, rtol=1e-5)
+    # the same samples spill less from the 4x larger reference buckets
+    assert int(spill_r.sum()) < int(spill_h.sum())
 
 
 # ---------------------------------------------------------------------------
