@@ -1,0 +1,12 @@
+"""Puts the benchmark's modules and the system under test on the path of
+the benchmark's CPU tests."""
+from __future__ import annotations
+
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+ROOT = BENCH.parent
+for p in (str(BENCH), str(ROOT / "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
