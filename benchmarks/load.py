@@ -97,16 +97,16 @@ def drive_open_loop(engine, specs: List[Dict], *, sid_base: int = 0,
     their tick-clock comes due (never waiting for completions — the
     open-loop contract: backlog builds when the engine falls behind).
 
-    Returns the phase's measurements: end-to-end frame latencies (queue
-    wait + per-frame render), queue-wait distribution, shed count, tick
-    wall-clocks, and per-run scene-cache / sweep deltas (the engine's
-    lifetime counters snapshotted here, the ``pool.recompiles``
-    convention)."""
+    Returns the phase's measurements: end-to-end frame latencies (from
+    arrival or the previous delivery to delivery), queue-wait
+    distribution, shed count, tick wall-clocks, and per-run scene-cache /
+    sweep deltas (the engine's lifetime counters snapshotted here, the
+    ``pool.recompiles`` convention)."""
     import numpy as np
 
     from repro.core import pipeline
     from repro.kernels import streaming_pipeline
-    from repro.serve.render_engine import RenderSession
+    from repro.serve.render_engine import RenderSession, delivery_latencies
 
     specs = sorted(specs, key=lambda d: d["arrive_tick"])
     sessions: List[RenderSession] = []
@@ -135,10 +135,8 @@ def drive_open_loop(engine, specs: List[Dict], *, sid_base: int = 0,
                 tick += 1  # idle gap in the arrival process
                 continue
             break
-        # closed per tick: block, attribute wall-clock, drain frames (the
-        # harness measures latency, so it forgoes run()'s 1-tick pipelining)
-        engine._observe_tick(tick_t0, engine._pending[-1][0],
-                             engine._last_result)
+        # closed per tick: block and deliver the frames (the harness
+        # measures latency, so it forgoes run()'s 1-tick pipelining)
         engine.finalize()
         tick_walls.append(time.time() - tick_t0)
         tick += 1
@@ -147,10 +145,11 @@ def drive_open_loop(engine, specs: List[Dict], *, sid_base: int = 0,
     served = [s for s in sessions if not s.shed]
     waits = [s.admitted_s - s.submitted_s for s in served
              if s.admitted_s is not None]
-    # end-to-end frame latency: queue wait + the frame's render share
-    e2e = [(s.admitted_s - s.submitted_s) + lat for s in served
-           if s.admitted_s is not None for lat in s.frame_latencies_s]
-    frames_done = sum(len(s.frame_latencies_s) for s in served)
+    # end-to-end frame latency: from the session's previous delivery (its
+    # arrival, for the first window, so the queue wait is in it) to the
+    # frame's delivery
+    e2e = [lat for s in served for lat in delivery_latencies(s)]
+    frames_done = len(e2e)
     ticks_run = engine.num_ticks - start_ticks
     adm_ticks = engine._num_admission_ticks - adm_start
 

@@ -416,14 +416,16 @@ class DeviceSparwEngine:
             overflowed = (frame_over | (tot_f > pool_caps)
                           | (tot_c > pool_caps_coarse))
             fine_counts = jnp.sum(fine, axis=2)
-        dense = jax.lax.cond(
-            jnp.any(overflowed),
-            lambda _: self._dense_fill_flat(params, tgt_poses),
-            lambda _: jnp.zeros_like(sparse),
-            None)
-        fill = jnp.where(overflowed[:, None, None, None], dense, sparse)
-        frames = jnp.where(holes[..., None], fill,
-                           warped.rgb.reshape(s, n, hw, 3))
+        with jax.named_scope("dense_fallback"):
+            dense = jax.lax.cond(
+                jnp.any(overflowed),
+                lambda _: self._dense_fill_flat(params, tgt_poses),
+                lambda _: jnp.zeros_like(sparse),
+                None)
+            fill = jnp.where(overflowed[:, None, None, None], dense, sparse)
+        with jax.named_scope("composite"):
+            frames = jnp.where(holes[..., None], fill,
+                               warped.rgb.reshape(s, n, hw, 3))
         return BatchedWindowResult(frames.reshape(s, n, h, w, 3),
                                    counts.astype(jnp.int32), overflowed,
                                    fine_counts.astype(jnp.int32))

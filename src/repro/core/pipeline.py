@@ -240,10 +240,12 @@ class CiceroRenderer:
         sessions = [RenderSession.from_request(req, sid=i)
                     for i, req in enumerate(reqs)]
         metrics = serve.run(sessions)
-        results = [RenderResult(frames=tuple(s.frames), stats=s.stats,
-                                wall_s=float(sum(s.frame_latencies_s)),
-                                sid=s.sid)
-                   for s in sessions]
+        # a session's wall-clock: from its arrival to its last delivery
+        results = [RenderResult(
+            frames=tuple(s.frames), stats=s.stats, sid=s.sid,
+            wall_s=max((t for t in s.delivered_s if t is not None),
+                       default=s.submitted_s) - s.submitted_s)
+            for s in sessions]
         return results, metrics
 
     def render_trajectories(self, trajectories: List[List[jnp.ndarray]],

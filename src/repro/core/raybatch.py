@@ -55,6 +55,7 @@ class FlatRays(NamedTuple):
     seg: jnp.ndarray      # [F] int32 — owning session per ray
 
 
+@jax.named_scope("compact")
 def pack_reference_rays(cam: rays.Camera, ref_poses: jnp.ndarray) -> FlatRays:
     """All S sessions' reference-frame rays as ONE flat batch [S*HW, 3]."""
     s = ref_poses.shape[0]
@@ -64,6 +65,7 @@ def pack_reference_rays(cam: rays.Camera, ref_poses: jnp.ndarray) -> FlatRays:
     return FlatRays(o.reshape(-1, 3), d.reshape(-1, 3), seg)
 
 
+@jax.named_scope("compact")
 def pack_hole_rays(cam: rays.Camera, tgt_poses: jnp.ndarray,
                    idx: jnp.ndarray) -> Tuple[FlatRays, jnp.ndarray]:
     """The tick's compacted hole samples as ONE fixed-capacity flat batch.
@@ -90,6 +92,7 @@ def pack_hole_rays(cam: rays.Camera, tgt_poses: jnp.ndarray,
     return FlatRays(osel, dsel, seg), addr
 
 
+@jax.named_scope("compact")
 def pack_hole_rays_pooled(cam: rays.Camera, tgt_poses: jnp.ndarray,
                           addr: jnp.ndarray) -> Tuple[FlatRays, jnp.ndarray]:
     """The tick's POOLED hole samples as one ``[S * bucket]`` flat batch.
@@ -117,6 +120,7 @@ def pack_hole_rays_pooled(cam: rays.Camera, tgt_poses: jnp.ndarray,
     return FlatRays(osel, dsel, seg), flat_addr
 
 
+@jax.named_scope("composite")
 def scatter_segments(values: jnp.ndarray, addr: jnp.ndarray,
                      valid: jnp.ndarray, size: int) -> jnp.ndarray:
     """Segment-scatter flat results back to frame pixels: ONE scatter.
@@ -197,10 +201,11 @@ def render_tick_streaming(model, params: dict, cam: rays.Camera, *,
     # ②③ warp LAST tick's reference into this tick's targets + pool holes
     warped = sparw.warp_frames_flat(rgb_ref, dep_ref, ref_poses, tgt_poses,
                                     cam, phi_deg=phi_deg)
-    holes = warped.holes.reshape(s, n, hw)
-    live = jnp.arange(n)[None, :] < win_lens[:, None]
-    counts = jnp.sum(holes & live[:, :, None], axis=2)
-    frame_over = jnp.max(jnp.where(live, counts, 0), axis=1) > caps
+    with jax.named_scope("compact"):
+        holes = warped.holes.reshape(s, n, hw)
+        live = jnp.arange(n)[None, :] < win_lens[:, None]
+        counts = jnp.sum(holes & live[:, :, None], axis=2)
+        frame_over = jnp.max(jnp.where(live, counts, 0), axis=1) > caps
     addr, totals = sparw.compact_holes_pooled(holes, bucket, live)
     hole_batch, flat_addr = pack_hole_rays_pooled(cam, tgt_poses, addr)
     ref_batch = pack_reference_rays(cam, next_ref_poses)
@@ -240,28 +245,31 @@ def render_tick_streaming(model, params: dict, cam: rays.Camera, *,
     valid = (jnp.arange(bucket)[None, :] < totals[:, None]).reshape(-1)
     sparse = scatter_segments(fill_col, flat_addr, valid,
                               s * n * hw).reshape(s, n, hw, 3)
-    hole_spill = feats.hole_overflow.reshape(-1, ns) & valid[:, None]
-    rit_counts = jnp.stack([
-        jnp.stack([jnp.sum(hole_spill), jnp.sum(valid) * ns]),
-        jnp.stack([jnp.sum(feats.ref_overflow),
-                   jnp.asarray(feats.ref_overflow.size)]),
-    ]).astype(jnp.int32)
+    with jax.named_scope("rit_fallback"):
+        hole_spill = feats.hole_overflow.reshape(-1, ns) & valid[:, None]
+        rit_counts = jnp.stack([
+            jnp.stack([jnp.sum(hole_spill), jnp.sum(valid) * ns]),
+            jnp.stack([jnp.sum(feats.ref_overflow),
+                       jnp.asarray(feats.ref_overflow.size)]),
+        ]).astype(jnp.int32)
     overflowed = frame_over | (totals > pool_caps)
+    fill = sparse
     if dense_fill is not None:
-        dense = jax.lax.cond(jnp.any(overflowed),
-                             lambda _: dense_fill(tgt_poses),
-                             lambda _: jnp.zeros_like(sparse), None)
-        fill = jnp.where(overflowed[:, None, None, None], dense, sparse)
-    else:
-        fill = sparse
-    frames = jnp.where(holes[..., None], fill,
-                       warped.rgb.reshape(s, n, hw, 3))
+        with jax.named_scope("dense_fallback"):
+            dense = jax.lax.cond(jnp.any(overflowed),
+                                 lambda _: dense_fill(tgt_poses),
+                                 lambda _: jnp.zeros_like(sparse), None)
+            fill = jnp.where(overflowed[:, None, None, None], dense, sparse)
+    with jax.named_scope("composite"):
+        frames = jnp.where(holes[..., None], fill,
+                           warped.rgb.reshape(s, n, hw, 3))
     return StreamingTickResult(
         frames.reshape(s, n, h, w, 3), counts.astype(jnp.int32),
         overflowed, counts.astype(jnp.int32),
         ref_col.reshape(s, h, w, 3), ref_dep.reshape(s, h, w), rit_counts)
 
 
+@jax.named_scope("composite")
 def substitute_reference_rows(mask: jnp.ndarray, rgb_new: jnp.ndarray,
                               dep_new: jnp.ndarray, rgb_ref: jnp.ndarray,
                               dep_ref: jnp.ndarray

@@ -142,6 +142,7 @@ def warp_frame(
     )
 
 
+@jax.named_scope("warp")
 def warp_frames_flat(
     rgb_ref: jnp.ndarray,  # [S, H, W, 3] per-session reference frames
     depth_ref: jnp.ndarray,  # [S, H, W]
@@ -239,6 +240,7 @@ def compact_holes(hflat: jnp.ndarray, cap: int
     return idx[:cap], hflat.sum()
 
 
+@jax.named_scope("compact")
 def compact_holes_flat(holes: jnp.ndarray, cap: int
                        ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Compact every (session, frame)'s holes in ONE flat scatter.
@@ -264,6 +266,7 @@ def compact_holes_flat(holes: jnp.ndarray, cap: int
     return idx.reshape(s, n, cap), hf.sum(axis=1).reshape(s, n)
 
 
+@jax.named_scope("compact")
 def compact_holes_pooled(holes: jnp.ndarray, bucket: int,
                          live: Optional[jnp.ndarray] = None
                          ) -> Tuple[jnp.ndarray, jnp.ndarray]:

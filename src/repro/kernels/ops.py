@@ -16,7 +16,6 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import fused_nerf_mlp as _mlp
 from repro.kernels import gather_trilerp as _gt
 from repro.kernels import streaming_pipeline as _sp
-from repro.nerf import grids
 from repro.utils import round_up
 
 
@@ -69,45 +68,49 @@ def gather_features_streaming(table: jnp.ndarray, points: jnp.ndarray,
             raise ValueError("mixed-scene gather needs the prebuilt stacked "
                              "mv_table [K, num_mv, P, C]")
         mv_table = streaming.build_mvoxel_table(table, cfg)  # [M, P, C]
-    mv = streaming.mvoxel_ids(points, cfg)
-    num_mv = cfg.num_mvoxels
-    if seg is not None and (num_seg > 1 or scened):
-        # combined (segment, mvoxel) bucket id, segment-major; padding
-        # segments land out of range and drop out of the table build
-        bucket = jnp.where(seg < num_seg, seg * num_mv + mv,
-                           num_seg * num_mv)
-        num_slots = num_seg * num_mv
-    else:
-        bucket, num_slots = mv, num_mv
-    rit = streaming.build_rit(bucket, cfg, num_slots=num_slots)
-    local_ids, w = streaming.local_corner_ids(points, cfg)
-    # match the (possibly bank-interleaved) physical row order of mv_table
-    local_ids = streaming.remap_local_ids(local_ids, cfg)
+    with jax.named_scope("rit_build"):
+        mv = streaming.mvoxel_ids(points, cfg)
+        num_mv = cfg.num_mvoxels
+        if seg is not None and (num_seg > 1 or scened):
+            # combined (segment, mvoxel) bucket id, segment-major; padding
+            # segments land out of range and drop out of the table build
+            bucket = jnp.where(seg < num_seg, seg * num_mv + mv,
+                               num_seg * num_mv)
+            num_slots = num_seg * num_mv
+        else:
+            bucket, num_slots = mv, num_mv
+        rit = streaming.build_rit(bucket, cfg, num_slots=num_slots)
+        local_ids, w = streaming.local_corner_ids(points, cfg)
+        # match the (possibly bank-interleaved) physical row order of mv_table
+        local_ids = streaming.remap_local_ids(local_ids, cfg)
+        # per-bucket sample blocks (RIT layout); padded columns use id 0 /
+        # weight 0
+        ids_mv, w_mv = _sp.rit_sample_blocks(local_ids, w, rit.samples)
 
-    # per-bucket sample blocks (RIT layout); padded columns use id 0 / weight 0
-    ids_mv, w_mv = _sp.rit_sample_blocks(local_ids, w, rit.samples)
-
-    if scened:
-        seg_tables = mv_table[scene_of_seg]  # [num_seg, num_mv, P, C]
-        out_mv = _gt.gather_trilerp_mvoxels_per_seg(
-            seg_tables, ids_mv, w_mv, num_seg=num_seg, interpret=interpret)
-    elif seg is not None and num_seg > 1:
-        out_mv = _gt.gather_trilerp_mvoxels_segmented(
-            mv_table, ids_mv, w_mv, num_seg=num_seg, interpret=interpret)
-    else:
-        out_mv = _gt.gather_trilerp_mvoxels(mv_table, ids_mv, w_mv,
-                                            interpret=interpret)
+    with jax.named_scope("gather"):
+        if scened:
+            seg_tables = mv_table[scene_of_seg]  # [num_seg, num_mv, P, C]
+            out_mv = _gt.gather_trilerp_mvoxels_per_seg(
+                seg_tables, ids_mv, w_mv, num_seg=num_seg,
+                interpret=interpret)
+        elif seg is not None and num_seg > 1:
+            out_mv = _gt.gather_trilerp_mvoxels_segmented(
+                mv_table, ids_mv, w_mv, num_seg=num_seg, interpret=interpret)
+        else:
+            out_mv = _gt.gather_trilerp_mvoxels(mv_table, ids_mv, w_mv,
+                                                interpret=interpret)
 
     feats = _sp.scatter_rit_outputs(out_mv, rit.samples, s)
 
     # overflow fallback (pixel-centric path for the spilled samples)
-    gids, gw = grids.corner_ids_weights(points, cfg.grid_res)
     if scened:
-        scn = scene_of_seg[jnp.clip(seg, 0, num_seg - 1)]
-        fallback = _sp.gather_trilerp_ref_scened(table, scn, gids, gw)
+        def gather(ids, weights):
+            scn = scene_of_seg[jnp.clip(seg, 0, num_seg - 1)]
+            return _sp.gather_trilerp_ref_scened(table, scn, ids, weights)
     else:
-        fallback = _sp.fallback_gather(table, gids, gw)
-    return jnp.where(rit.overflow[:, None], fallback, feats)
+        def gather(ids, weights):
+            return _sp.fallback_gather(table, ids, weights)
+    return _sp.select_fallback(feats, rit.overflow, points, cfg, gather)
 
 
 # ---------------------------------------------------------------------------

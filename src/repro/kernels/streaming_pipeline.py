@@ -183,6 +183,7 @@ def rit_sample_blocks(local_ids: jnp.ndarray, w: jnp.ndarray,
     return ids_mv, w_mv
 
 
+@jax.named_scope("rit_scatter")
 def scatter_rit_outputs(out_mv: jnp.ndarray, samples: jnp.ndarray,
                         t: int) -> jnp.ndarray:
     """RIT-order kernel output ``[num_slots, C, cap]`` back to sample
@@ -193,6 +194,7 @@ def scatter_rit_outputs(out_mv: jnp.ndarray, samples: jnp.ndarray,
     return jnp.zeros((t + 1, c), out_mv.dtype).at[flat_sample].set(rows)[:t]
 
 
+@jax.named_scope("rit_build")
 def _rit_blocks(points: jnp.ndarray, seg: jnp.ndarray, num_seg: int,
                 cfg: streaming.StreamingCfg) -> _RitBlocks:
     """Bucket one sample set per (segment, MVoxel) and lay its corner
@@ -215,9 +217,19 @@ def _scatter_with_fallback(out_mv: jnp.ndarray, blocks: _RitBlocks,
     take the reference (pixel-centric) gather on the ORIGINAL table — the
     paper's fallback, layout-independent by construction."""
     feats = scatter_rit_outputs(out_mv, blocks.samples, points.shape[0])
+    return select_fallback(feats, blocks.overflow, points, cfg,
+                           lambda ids, w: fallback_gather(table, ids, w))
+
+
+@jax.named_scope("rit_fallback")
+def select_fallback(feats: jnp.ndarray, overflow: jnp.ndarray,
+                    points: jnp.ndarray, cfg: streaming.StreamingCfg,
+                    gather) -> jnp.ndarray:
+    """Samples whose ``overflow`` [T] is set take ``gather(ids, weights)``
+    over their dense-grid corners in place of their kernel output
+    ``feats`` [T, C] (the fallback is computed for every sample)."""
     gids, gw = grids.corner_ids_weights(points, cfg.grid_res)
-    fallback = fallback_gather(table, gids, gw)
-    return jnp.where(blocks.overflow[:, None], fallback, feats)
+    return jnp.where(overflow[:, None], gather(gids, gw), feats)
 
 
 def gather_trilerp_ref_scened(tables: jnp.ndarray, scene: jnp.ndarray,
@@ -246,9 +258,9 @@ def _scatter_with_fallback_scened(out_mv: jnp.ndarray, blocks: _RitBlocks,
     """Mixed-scene :func:`_scatter_with_fallback`: the overflow fallback
     reads each sample's own scene's ORIGINAL dense table."""
     feats = scatter_rit_outputs(out_mv, blocks.samples, points.shape[0])
-    gids, gw = grids.corner_ids_weights(points, cfg.grid_res)
-    fallback = gather_trilerp_ref_scened(tables, scene, gids, gw)
-    return jnp.where(blocks.overflow[:, None], fallback, feats)
+    return select_fallback(
+        feats, blocks.overflow, points, cfg,
+        lambda ids, w: gather_trilerp_ref_scened(tables, scene, ids, w))
 
 
 class TickFeatures(NamedTuple):
@@ -284,10 +296,11 @@ def gather_features_tick_scenes(tables: jnp.ndarray, mv_tables: jnp.ndarray,
         cfg, capacity=cfg.capacity * ref_cap_factor)
     bh = _rit_blocks(pts_hole, seg_hole, num_seg, cfg)
     br = _rit_blocks(pts_ref, seg_ref, num_seg, cfg_ref)
-    seg_tables = mv_tables[scene_of_seg]  # [num_seg, num_mv, P, C]
-    out_h, out_r = fused_gather_dual_per_seg(
-        seg_tables, bh.ids_mv, bh.w_mv, br.ids_mv, br.w_mv,
-        num_seg=num_seg, interpret=interpret)
+    with jax.named_scope("gather"):
+        seg_tables = mv_tables[scene_of_seg]  # [num_seg, num_mv, P, C]
+        out_h, out_r = fused_gather_dual_per_seg(
+            seg_tables, bh.ids_mv, bh.w_mv, br.ids_mv, br.w_mv,
+            num_seg=num_seg, interpret=interpret)
     scn_h = scene_of_seg[jnp.clip(seg_hole, 0, num_seg - 1)]
     scn_r = scene_of_seg[jnp.clip(seg_ref, 0, num_seg - 1)]
     feats_h = _scatter_with_fallback_scened(out_h, bh, tables, scn_h,
@@ -320,9 +333,10 @@ def gather_features_tick(table: jnp.ndarray, mv_table: jnp.ndarray,
         cfg, capacity=cfg.capacity * ref_cap_factor)
     bh = _rit_blocks(pts_hole, seg_hole, num_seg, cfg)
     br = _rit_blocks(pts_ref, seg_ref, num_seg, cfg_ref)
-    out_h, out_r = fused_gather_dual(mv_table, bh.ids_mv, bh.w_mv,
-                                     br.ids_mv, br.w_mv, num_seg=num_seg,
-                                     interpret=interpret)
+    with jax.named_scope("gather"):
+        out_h, out_r = fused_gather_dual(mv_table, bh.ids_mv, bh.w_mv,
+                                         br.ids_mv, br.w_mv, num_seg=num_seg,
+                                         interpret=interpret)
     feats_h = _scatter_with_fallback(out_h, bh, table, pts_hole, cfg)
     feats_r = _scatter_with_fallback(out_r, br, table, pts_ref, cfg)
     return TickFeatures(feats_h, feats_r, bh.overflow, br.overflow)

@@ -233,6 +233,7 @@ class NerfModel:
                                     seg=seg, num_seg=num_seg)
         return self.decode_features(params, feats, dirs, backend=backend)
 
+    @jax.named_scope("decode")
     def decode_features(self, params: dict, feats: jnp.ndarray,
                         dirs: jnp.ndarray, backend: Optional[str] = None
                         ) -> Tuple[jnp.ndarray, jnp.ndarray]:

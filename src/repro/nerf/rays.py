@@ -111,6 +111,7 @@ def generate_rays_batch(cam: Camera, c2ws: jnp.ndarray
     return jax.vmap(lambda p: generate_rays(cam, p))(c2ws)
 
 
+@jax.named_scope("compact")
 def sample_along_rays(
     origins: jnp.ndarray,
     dirs: jnp.ndarray,

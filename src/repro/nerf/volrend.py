@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("composite")
 def composite(
     sigmas: jnp.ndarray,  # [R, N]
     rgbs: jnp.ndarray,  # [R, N, 3]

@@ -74,6 +74,7 @@ mid-trajectory.
 """
 from __future__ import annotations
 
+import itertools
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple, Union
@@ -82,6 +83,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.analysis.jitprobe import JitCacheProbe
 from repro.core import schedule, streaming
 from repro.core.config import (
     _UNSET,
@@ -108,15 +110,18 @@ class RenderSession:
     names which scene this client views (None = the engine's default
     params; non-None requires a multi-scene engine). ``arrival`` and
     ``submitted_s`` are stamped by :meth:`RenderServeEngine.submit`,
-    ``admitted_s`` when the session takes a slot; ``shed=True`` marks a
-    session the policy dropped from the queue (done without frames).
+    ``admitted_s`` when the session takes a slot, and ``delivered_s[f]``
+    when frame ``f`` reaches the host in :meth:`RenderServeEngine.finalize`
+    (:func:`delivery_latencies` turns them into per-frame latencies);
+    ``shed=True`` marks a session the policy dropped from the queue (done
+    without frames).
     """
 
     sid: int
     poses: List[jnp.ndarray]  # the trajectory (absorbed window by window)
     frames: List[Optional[jnp.ndarray]] = field(default_factory=list)
     stats: RenderStats = field(default_factory=RenderStats)
-    frame_latencies_s: List[float] = field(default_factory=list)
+    delivered_s: List[Optional[float]] = field(default_factory=list)
     done: bool = False
     window: Optional[int] = None      # per-session warp window override
     hole_cap: Optional[int] = None    # per-session sparse-capacity override
@@ -133,6 +138,7 @@ class RenderSession:
         if not self.poses:
             raise ValueError(f"session {self.sid}: empty trajectory")
         self.frames = [None] * len(self.poses)
+        self.delivered_s = [None] * len(self.poses)
 
     @classmethod
     def from_request(cls, request: RenderRequest, sid: int) -> "RenderSession":
@@ -144,6 +150,20 @@ class RenderSession:
                    priority=request.priority,
                    deadline_ms=request.deadline_ms,
                    scene=request.scene)
+
+
+def delivery_latencies(sess: RenderSession) -> List[float]:
+    """Per delivered frame: the seconds from the session's previous
+    delivery (its arrival, for the first window) to the delivery that
+    brought the frame to the host. Frames delivered together share one
+    latency."""
+    out: List[float] = []
+    prev = sess.submitted_s
+    for stamp, group in itertools.groupby(
+            t for t in sess.delivered_s if t is not None):
+        out += [stamp - prev] * len(list(group))
+        prev = stamp
+    return out
 
 
 @dataclass
@@ -357,22 +377,24 @@ class RenderServeEngine:
                     "slot (more distinct scenes in flight than num_slots "
                     "pages — should be unreachable, slots == pages)")
         page = self._free_pages.pop()
-        if skey is None:
-            table, mv = self._default_table, self._default_mv
-        else:
-            loaded = self.scene_loader(skey)
-            table = loaded["table"] if isinstance(loaded, dict) else loaded
-            table = jnp.asarray(table, self._default_table.dtype)
-            if table.shape != self._default_table.shape:
-                raise ValueError(
-                    f"scene {skey!r}: table shape {table.shape} differs "
-                    f"from the engine's compiled page shape "
-                    f"{self._default_table.shape} (all scenes share one "
-                    f"grid geometry)")
-            mv = streaming.build_mvoxel_table(
-                table, self.engine.model.streaming_cfg)
-        self._table_stack = self._table_stack.at[page].set(table)
-        self._mv_stack = self._mv_stack.at[page].set(mv)
+        with jax.profiler.TraceAnnotation("serve.upload"):
+            if skey is None:
+                table, mv = self._default_table, self._default_mv
+            else:
+                loaded = self.scene_loader(skey)
+                table = (loaded["table"] if isinstance(loaded, dict)
+                         else loaded)
+                table = jnp.asarray(table, self._default_table.dtype)
+                if table.shape != self._default_table.shape:
+                    raise ValueError(
+                        f"scene {skey!r}: table shape {table.shape} differs "
+                        f"from the engine's compiled page shape "
+                        f"{self._default_table.shape} (all scenes share one "
+                        f"grid geometry)")
+                mv = streaming.build_mvoxel_table(
+                    table, self.engine.model.streaming_cfg)
+            self._table_stack = self._table_stack.at[page].set(table)
+            self._mv_stack = self._mv_stack.at[page].set(mv)
         nbytes = int(table.nbytes) + int(mv.nbytes)
         self._num_uploads += 1
         self._uploaded_bytes += nbytes
@@ -574,20 +596,61 @@ class RenderServeEngine:
         its row (ref pose == idle target pose ⇒ the idle self-warp stays
         hole-free), so a freed slot's recurrence is self-consistent until
         prime-on-admit overwrites it for the next occupant."""
-        newly = self._admit()
-        occupied = sum(s is not None for s in self.slots)
-        if occupied == 0:
-            return False
-        # post-admission backlog + occupancy telemetry (per-tick; run()
-        # reports per-run slices of these lifetime logs)
-        self._queue_depth_log.append(len(self.queue))
-        self._occupancy_log.append(occupied)
-        self._stage_slot_masks()
-        if self.multi_scene:
-            self._stage_scene_map()
-        if self.fused:
-            self._prime_admitted(newly)
+        with jax.profiler.TraceAnnotation("serve.step"):
+            return self._step()
 
+    def _step(self) -> bool:
+        with jax.profiler.TraceAnnotation("serve.admit"):
+            newly = self._admit()
+            occupied = sum(s is not None for s in self.slots)
+            if occupied == 0:
+                return False
+            # post-admission backlog + occupancy telemetry (per-tick; run()
+            # reports per-run slices of these lifetime logs)
+            self._queue_depth_log.append(len(self.queue))
+            self._occupancy_log.append(occupied)
+        with jax.profiler.TraceAnnotation("serve.stage"):
+            self._stage_slot_masks()
+            if self.multi_scene:
+                self._stage_scene_map()
+        if self.fused:
+            with jax.profiler.TraceAnnotation("serve.prime"):
+                self._prime_admitted(newly)
+
+        with jax.profiler.TraceAnnotation("serve.stage"):
+            ref_poses, tgt_poses, next_refs, assignments = self._stage_poses()
+            ref_poses = jnp.stack(ref_poses)
+            tgt_poses = jnp.stack([jnp.stack(t) for t in tgt_poses])
+            if self.fused:
+                next_refs = jnp.stack(next_refs)
+        with jax.profiler.TraceAnnotation("serve.dispatch"):
+            if self.fused:
+                result = self.engine.render_windows_streaming(
+                    self._rgb_ref, self._dep_ref, ref_poses, tgt_poses,
+                    next_refs, self._win_lens, self._caps,
+                    pool_caps=self._pool_caps, bucket=self._tick_bucket)
+                # thread the co-rendered references to the next dispatch —
+                # device-resident, never synced
+                self._rgb_ref = result.next_rgb_ref
+                self._dep_ref = result.next_dep_ref
+            else:
+                result = self.engine.render_windows(
+                    ref_poses, tgt_poses, self._win_lens, self._caps,
+                    pool_caps=self._pool_caps,
+                    pool_caps_coarse=self._pool_caps_c,
+                    bucket=self._tick_bucket,
+                    bucket_coarse=self._tick_bucket_c)
+        self._pending.append(
+            (assignments, result, (self._tick_bucket, self._tick_bucket_c)))
+        self._last_result = result
+        self.num_ticks += 1
+        return True
+
+    def _stage_poses(self) -> Tuple[list, list, list, list]:
+        """Each slot's reference pose, padded target window and next
+        reference pose (the idle pose for empty slots), and the tick's
+        assignments; advances every occupied slot's cursor and frees the
+        slots whose trajectory this tick ends."""
         ref_poses, tgt_poses, next_refs, assignments = [], [], [], []
         for s in range(self.num_slots):
             slot = self.slots[s]
@@ -630,30 +693,7 @@ class RenderServeEngine:
                 next_refs.append(slot.ref_pose)
             else:
                 next_refs.append(self._idle_pose)
-
-        if self.fused:
-            result = self.engine.render_windows_streaming(
-                self._rgb_ref, self._dep_ref, jnp.stack(ref_poses),
-                jnp.stack([jnp.stack(t) for t in tgt_poses]),
-                jnp.stack(next_refs), self._win_lens, self._caps,
-                pool_caps=self._pool_caps, bucket=self._tick_bucket)
-            # thread the co-rendered references to the next dispatch —
-            # device-resident, never synced
-            self._rgb_ref = result.next_rgb_ref
-            self._dep_ref = result.next_dep_ref
-        else:
-            result = self.engine.render_windows(
-                jnp.stack(ref_poses),
-                jnp.stack([jnp.stack(t) for t in tgt_poses]),
-                self._win_lens, self._caps,
-                pool_caps=self._pool_caps,
-                pool_caps_coarse=self._pool_caps_c,
-                bucket=self._tick_bucket, bucket_coarse=self._tick_bucket_c)
-        self._pending.append(
-            (assignments, result, (self._tick_bucket, self._tick_bucket_c)))
-        self._last_result = result
-        self.num_ticks += 1
-        return True
+        return ref_poses, tgt_poses, next_refs, assignments
 
     # ------------------------------------------------------------------
     def finalize(self, keep: int = 0) -> None:
@@ -667,51 +707,45 @@ class RenderServeEngine:
         split = max(len(self._pending) - keep, 0)
         done, self._pending = self._pending[:split], self._pending[split:]
         for assignments, res, (bf, bc) in done:
-            if self.fused:
-                self._rit_counts += np.asarray(res.rit_counts)
-            counts = np.asarray(res.hole_counts)
-            fine = np.asarray(res.fine_counts)
-            overflowed = np.asarray(res.overflowed)
-            tick_holes = tick_fine = active = 0
-            for s, assign in enumerate(assignments):
-                if assign is None:
-                    continue
-                sess, idxs, ctl, ctl_c = assign
-                ovf = bool(overflowed[s])
-                for j, f in enumerate(idxs):
-                    sess.frames[f] = res.frames[s, j]
-                    sess.stats.record_frame(int(counts[s, j]), ovf, hw)
-                if sess.frames.count(None) == 0:
-                    sess.done = True
-                win_total = int(counts[s, :len(idxs)].sum())
-                fine_total = int(fine[s, :len(idxs)].sum())
-                tick_holes += win_total
-                tick_fine += fine_total
-                active += 1
-                # feed the session's pool controllers — the readback runs a
-                # tick behind dispatch, so observations land two dispatches
-                # after the window they describe (the cadence the exclusive
-                # engine's render_trajectory mirrors)
-                if pool and ctl is not None:
-                    ctl.observe(fine_total)
-                    if adaptive:
-                        ctl_c.observe(win_total - fine_total)
-            if pool:
-                self._pool_log.append(dict(
-                    bucket=bf, bucket_coarse=bc, hole_total=tick_holes,
-                    fine_total=tick_fine, active_slots=active))
-
-    def _observe_tick(self, tick_t0: float, assignments: List[tuple],
-                      result) -> None:
-        """Block until a dispatched tick's device work completes and
-        attribute its wall-clock to the sessions it served (a short tail
-        window pays the whole tick over fewer frames)."""
-        jax.block_until_ready(result.frames)
-        tick_s = time.time() - tick_t0
-        for assign in assignments:
-            if assign is not None:
-                sess, idxs = assign[0], assign[1]
-                sess.frame_latencies_s.extend([tick_s / len(idxs)] * len(idxs))
+            with jax.profiler.TraceAnnotation("serve.readback"):
+                rit = np.asarray(res.rit_counts) if self.fused else None
+                counts = np.asarray(res.hole_counts)
+                fine = np.asarray(res.fine_counts)
+                overflowed = np.asarray(res.overflowed)
+            delivered = time.time()
+            with jax.profiler.TraceAnnotation("serve.bookkeep"):
+                if rit is not None:
+                    self._rit_counts += rit
+                tick_holes = tick_fine = active = 0
+                for s, assign in enumerate(assignments):
+                    if assign is None:
+                        continue
+                    sess, idxs, ctl, ctl_c = assign
+                    ovf = bool(overflowed[s])
+                    for j, f in enumerate(idxs):
+                        sess.frames[f] = res.frames[s, j]
+                        sess.delivered_s[f] = delivered
+                        sess.stats.record_frame(int(counts[s, j]), ovf, hw)
+                    if sess.frames.count(None) == 0:
+                        sess.done = True
+                    win_total = int(counts[s, :len(idxs)].sum())
+                    fine_total = int(fine[s, :len(idxs)].sum())
+                    tick_holes += win_total
+                    tick_fine += fine_total
+                    active += 1
+                    # feed the session's pool controllers — the readback
+                    # runs a tick behind dispatch, so observations land two
+                    # dispatches after the window they describe (the
+                    # cadence the exclusive engine's render_trajectory
+                    # mirrors)
+                    if pool and ctl is not None:
+                        ctl.observe(fine_total)
+                        if adaptive:
+                            ctl_c.observe(win_total - fine_total)
+                if pool:
+                    self._pool_log.append(dict(
+                        bucket=bf, bucket_coarse=bc, hole_total=tick_holes,
+                        fine_total=tick_fine, active_slots=active))
 
     def run(self, sessions: List[RenderSession], max_ticks: int = 10_000
             ) -> Dict[str, object]:
@@ -722,11 +756,15 @@ class RenderServeEngine:
         (admission, pose staging) overlaps device compute instead of
         serializing against it — the continuous-batching analogue of the
         single-session engine's dispatch-then-read-back discipline.
-        Per-session frame latencies are still wall-clock per tick
-        (dispatch → observed completion), and completed ticks are drained
-        as the loop advances so device memory stays bounded at the
-        pipeline depth regardless of trajectory length. The zero-host-sync
-        contract applies to bare :meth:`step`, not :meth:`run`.
+        A frame's latency runs from its session's previous delivery (its
+        arrival, for the first window) to the delivery that brought it to
+        the host (:func:`delivery_latencies`), and completed ticks are
+        drained as the loop advances so device memory stays bounded at the
+        pipeline depth regardless of trajectory length. ``compiles`` counts
+        the new jit-cache entries of the engine's programs in this run
+        (:class:`~repro.analysis.jitprobe.JitCacheProbe`). The
+        zero-host-sync contract applies to bare :meth:`step`, not
+        :meth:`run`.
         """
         self.submit(sessions)
         start_ticks = self.num_ticks  # the engine may be reused across runs
@@ -744,30 +782,30 @@ class RenderServeEngine:
                          uploads=self._num_uploads,
                          uploaded_bytes=self._uploaded_bytes)
                     if self.multi_scene else None)
+        probe = JitCacheProbe(self.engine)
         t0 = time.time()
-        in_flight = None  # (dispatch_t0, assignments, device result)
+        in_flight = None  # the device result of the tick still running
         while self.num_ticks - start_ticks < max_ticks:
-            tick_t0 = time.time()
             if not self.step():
                 break
-            dispatched = (tick_t0, self._pending[-1][0], self._last_result)
             if in_flight is not None:
-                self._observe_tick(*in_flight)
+                jax.block_until_ready(in_flight.frames)
                 self.finalize(keep=1)  # drain all completed ticks
-            in_flight = dispatched
+            in_flight = self._last_result
         if in_flight is not None:
-            self._observe_tick(*in_flight)
+            jax.block_until_ready(in_flight.frames)
         wall_s = time.time() - t0
         self.finalize()
+        latencies = {s.sid: delivery_latencies(s) for s in sessions}
         # shed sessions render nothing — they must not inflate throughput
         total_frames = sum(len(s.poses) for s in sessions if not s.shed)
         per_session = {
             s.sid: {
                 "frames": len(s.poses),
-                "p50_latency_s": float(np.percentile(s.frame_latencies_s, 50))
-                if s.frame_latencies_s else float("nan"),
-                "p95_latency_s": float(np.percentile(s.frame_latencies_s, 95))
-                if s.frame_latencies_s else float("nan"),
+                "p50_latency_s": float(np.percentile(latencies[s.sid], 50))
+                if latencies[s.sid] else float("nan"),
+                "p95_latency_s": float(np.percentile(latencies[s.sid], 95))
+                if latencies[s.sid] else float("nan"),
                 "hole_fraction": s.stats.mean_hole_fraction,
                 "scene": s.scene,
                 "shed": s.shed,
@@ -877,6 +915,7 @@ class RenderServeEngine:
                 for i, stage in enumerate(("hole", "ref"))}
         return {
             "ticks": self.num_ticks - start_ticks,
+            "compiles": probe.recompiles(),
             "wall_s": wall_s,
             "aggregate_fps": total_frames / max(wall_s, 1e-9),
             "total_frames": total_frames,

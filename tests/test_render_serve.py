@@ -9,7 +9,8 @@ import pytest
 from repro.core import pipeline
 from repro.core.config import RenderConfig
 from repro.nerf import models, rays, scenes
-from repro.serve.render_engine import RenderServeEngine, RenderSession
+from repro.serve.render_engine import (RenderServeEngine, RenderSession,
+                                       delivery_latencies)
 from repro.utils import psnr
 
 
@@ -433,3 +434,39 @@ def test_fused_serving_tick_zero_host_syncs(fused_setup):
     serve.finalize()
     assert serve._pending == []
     assert all(slot is None for slot in serve.slots)  # fully drained
+
+
+def test_delivery_latencies_run_from_the_previous_delivery():
+    """A frame waits from its session's previous delivery (its arrival,
+    for the first window) to its own; frames delivered together share it,
+    and undelivered frames have none."""
+    sess = RenderSession(sid=0, poses=[jnp.eye(4)] * 5)
+    sess.submitted_s = 10.0
+    sess.delivered_s = [12.0, 12.0, 15.5, 15.5, None]
+    assert delivery_latencies(sess) == [2.0, 2.0, 3.5, 3.5]
+
+
+def test_run_reports_delivery_latencies_and_compiles(small_model, cam):
+    """run()'s per-session p50/p95 come from the delivery stamps
+    finalize() writes, and ``compiles`` counts the engine programs' new
+    jit-cache entries in that run: none when a second run reuses the
+    shapes."""
+    model, params = small_model
+    serve = RenderServeEngine(model, params,
+                              config=_cfg(cam, num_slots=2, window=2))
+    first = [RenderSession(sid=i, poses=list(t))
+             for i, t in enumerate(_trajs(2, 3))]
+    metrics = serve.run(first)
+    assert metrics["compiles"] >= 1
+    for sess in first:
+        assert all(t is not None for t in sess.delivered_s)
+        lat = delivery_latencies(sess)
+        assert len(lat) == 3 and min(lat) > 0
+        # two windows: the first from arrival, the second from the first
+        assert lat[0] == lat[1] == sess.delivered_s[0] - sess.submitted_s
+        m = metrics["per_session"][sess.sid]
+        assert m["p50_latency_s"] == pytest.approx(np.percentile(lat, 50))
+        assert m["p95_latency_s"] == pytest.approx(np.percentile(lat, 95))
+    again = serve.run([RenderSession(sid=i, poses=list(t))
+                       for i, t in enumerate(_trajs(2, 3))])
+    assert again["compiles"] == 0

@@ -10,6 +10,7 @@ temporaries fit one v5e's HBM. A kernel the chip's compiler refuses, or
 an operand layout that pads past the chip's memory, fails here.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -109,3 +110,37 @@ def test_kernel_compiles_for_v5e_and_fits(name, one_chip,
     assert used <= HBM_BYTES, (
         f"{name}: arguments + temporaries {used / 1e9:.2f} GB exceed one "
         f"v5e's {HBM_BYTES / 1e9} GB")
+
+
+def _assert_keeps_its_name(name, scope, one_chip):
+    # a profile names a kernel's event after the instruction, which takes
+    # the name of the jitted function around the pallas_call; the roofline
+    # metrics read it. A scope opened inside that function renames it.
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn, args = _kernel_call(name, sds)
+
+    def scoped(*a):
+        with jax.named_scope(scope):
+            return fn(*a)
+
+    text = jax.jit(scoped).lower(*args).compile().as_text()
+    assert re.search(rf"^\s*%{name}\.\d+ = .* custom-call\(", text, re.M)
+
+
+@pytest.mark.parametrize("name", [
+    "gather_trilerp_mvoxels_segmented",
+    "gather_trilerp_mvoxels_per_seg",
+    "fused_gather_dual",
+    "fused_gather_dual_per_seg",
+])
+def test_kernel_keeps_its_name_under_the_gather_scope(name, one_chip,
+                                                      no_persistent_cache):
+    _assert_keeps_its_name(name, "gather", one_chip)
+
+
+def test_mlp_kernel_keeps_its_name_under_the_decode_scope(
+        one_chip, no_persistent_cache):
+    # NerfModel.decode_features opens ``decode`` around ops.nerf_mlp
+    _assert_keeps_its_name("fused_nerf_mlp", "decode", one_chip)
