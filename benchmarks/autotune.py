@@ -1,10 +1,10 @@
-"""Block/grid-size autotuner for the streaming Pallas kernels.
+"""Block-size autotuner for the streaming Pallas kernel.
 
-The segmented gather and the fused streaming pipeline both tile their work
-as ``[num_seg * num_mv, ..., cap]`` RIT blocks: ``cap`` (samples per
-(segment, MVoxel) block) fixes the Pallas block shape; the fused kernel
-gives its reference set twice the hole capacity, as the serving tick
-does. The best block size is hardware-dependent (MXU tile
+Every gather path (the fused tick's ``fused_gather_dual``, the staged
+path's and the prime's ``gather_trilerp_mvoxels_segmented``) runs one
+ragged-RIT kernel over ``[n_blocks, ..., cap]`` blocks: ``cap`` (samples
+per RIT block) fixes the Pallas block shape, and an MVoxel's run pads up
+to a whole block. The best block size is hardware-dependent (MXU tile
 amortization vs VMEM footprint vs padding waste), so instead of hardcoding
 it we sweep a pow2 ladder, time each candidate on synthetic RIT blocks at
 the config's true streaming shapes, and cache the winner keyed on
@@ -57,20 +57,25 @@ def _time_best(fn, reps: int = 3) -> float:
     return best
 
 
-def _synthetic_blocks(key, num_seg: int, num_mv: int, cap: int, p: int,
-                      channels: int):
-    """Synthetic RIT blocks at the kernel's true shapes: uniform random
-    local ids + unit-sum weights (the kernel's cost is id-independent —
-    one-hot matmuls — so uniform ids time the real schedule)."""
+def _synthetic_blocks(key, samples: int, num_mv: int, cap: int, p: int):
+    """A synthetic ragged RIT at the kernel's true shapes: ``samples``
+    samples spread uniformly over ``num_mv`` MVoxels, cut into blocks of
+    ``cap``, with uniform random local ids and unit-sum weights (the
+    kernel's cost is id-independent — one-hot matmuls — so uniform ids
+    time the real schedule)."""
     import jax
     import jax.numpy as jnp
 
-    k1, k2 = jax.random.split(key)
-    ids = jax.random.randint(k1, (num_seg * num_mv, 8, cap), 0, p,
-                             dtype=jnp.int32)
-    w = jax.random.uniform(k2, (num_seg * num_mv, 8, cap), jnp.float32)
-    w = w / jnp.sum(w, axis=1, keepdims=True)
-    return ids, w
+    from repro.core import streaming
+
+    k1, k2, k3 = jax.random.split(key, 3)
+    rit = streaming.build_rit(
+        jax.random.randint(k1, (samples,), 0, num_mv, dtype=jnp.int32),
+        num_mv, cap)
+    shape = (rit.sample.shape[0], 8, cap)
+    ids = jax.random.randint(k2, shape, 0, p, dtype=jnp.int32)
+    w = jax.random.uniform(k3, shape, jnp.float32)
+    return rit, ids, w / jnp.sum(w, axis=1, keepdims=True)
 
 
 def _cap_ladder(base_cap: int, smoke: bool) -> List[int]:
@@ -87,14 +92,13 @@ def autotune(cfg, *, cache_path: Path = DEFAULT_CACHE, force: bool = False,
     ``cfg`` is a (resolved) :class:`repro.core.config.RenderConfig`; the
     sweep runs at its true streaming shapes (grid_res / MVoxel edge /
     channels, ``num_seg`` sessions — default ``cfg.num_slots``). Returns
-    the cache entry: per-kernel candidate timings plus the winning
-    ``capacity`` of each kernel.
+    the cache entry: candidate timings plus the winning ``capacity``.
     """
     import jax
     import jax.numpy as jnp
 
     from repro.core import streaming
-    from repro.kernels import gather_trilerp, streaming_pipeline
+    from repro.kernels import gather_trilerp
 
     key = cfg.fingerprint()
     cache = _load_cache(cache_path)
@@ -111,30 +115,22 @@ def autotune(cfg, *, cache_path: Path = DEFAULT_CACHE, force: bool = False,
     rng = jax.random.PRNGKey(0)
     mv_table = jax.random.normal(rng, (num_mv, p, c), jnp.float32)
 
-    # --- segmented gather: sweep the per-block RIT capacity --------------
-    seg_rows = []
+    # --- ragged gather: sweep the RIT block width over one tick's samples
+    # (every session's frame of reference samples and a quarter frame of
+    # hole samples)
+    samples = s * cfg.res * cfg.res * cfg.num_samples * 5 // 4
+    rows = []
     for cap in _cap_ladder(cfg.stream_capacity, smoke):
-        ids, w = _synthetic_blocks(rng, s, num_mv, cap, p, c)
+        rit, ids, w = _synthetic_blocks(rng, samples, num_mv, cap, p)
         wall = _time_best(lambda: gather_trilerp.gather_trilerp_mvoxels_segmented(
-            mv_table, ids, w, num_seg=s, interpret=interpret))
-        # normalize to per-sample-slot cost: bigger blocks do more work
-        # per call, the tuner optimizes throughput, not latency
-        seg_rows.append({"capacity": cap, "wall_s": wall,
-                         "ns_per_slot": wall * 1e9 / (s * num_mv * cap)})
-    seg_best = min(seg_rows, key=lambda r: r["ns_per_slot"])
-
-    # --- fused pipeline: sweep the hole capacity (reference at 2x) --------
-    fused_rows = []
-    for cap in _cap_ladder(cfg.stream_capacity, smoke):
-        ids_h, w_h = _synthetic_blocks(rng, s, num_mv, cap, p, c)
-        ids_r, w_r = _synthetic_blocks(rng, s, num_mv, cap * 2, p, c)
-        wall = _time_best(lambda: streaming_pipeline.fused_gather_dual(
-            mv_table, ids_h, w_h, ids_r, w_r, num_seg=s,
+            mv_table, rit.block_key, rit.n_live, ids, w,
             interpret=interpret))
-        slots = s * num_mv * cap * 3
-        fused_rows.append({"capacity": cap, "wall_s": wall,
-                           "ns_per_slot": wall * 1e9 / slots})
-    fused_best = min(fused_rows, key=lambda r: r["ns_per_slot"])
+        # normalize to per-sample cost: the tuner optimizes throughput,
+        # padding included
+        rows.append({"capacity": cap, "wall_s": wall,
+                     "live_blocks": int(rit.n_live[0]),
+                     "ns_per_sample": wall * 1e9 / samples})
+    best = min(rows, key=lambda r: r["ns_per_sample"])
 
     entry = {
         "config_fingerprint": key,
@@ -143,8 +139,8 @@ def autotune(cfg, *, cache_path: Path = DEFAULT_CACHE, force: bool = False,
         "halo_rows": p,
         "channels": c,
         "pallas_interpret": interpret,
-        "segmented_gather": {"best": seg_best, "candidates": seg_rows},
-        "fused_pipeline": {"best": fused_best, "candidates": fused_rows},
+        "samples": samples,
+        "ragged_gather": {"best": best, "candidates": rows},
     }
     cache[key] = entry
     cache_path.parent.mkdir(parents=True, exist_ok=True)

@@ -493,9 +493,12 @@ def bench_memory(sessions: int = 4, res: int = 64, window: int = 4,
     bucket = eng_s._current_buckets()[0]
     mem = eng_s.tick_memory_stats(s, window, bucket=bucket)
     scfg = shared.model.streaming_cfg
+    # the tick's merged stream: every session's pooled holes and its next
+    # reference frame, num_samples samples per ray
+    hw = eng_s.cam.height * eng_s.cam.width
     fused_traffic = streaming_pipeline.tick_traffic(
-        scfg, shared.model.cfg.feat_channels, s,
-        cap_hole=scfg.capacity, cap_ref=2 * scfg.capacity)
+        scfg, shared.model.cfg.feat_channels,
+        s * (bucket + hw) * shared.model.cfg.num_samples)
 
     # --- HLO-derived total bytes of the actual jitted ticks ---------------
     refs0, tgts0 = tick_poses(0)

@@ -30,8 +30,8 @@ ROOT = Path(__file__).resolve().parent
 
 # The smoke's serving shape, chosen from the v5e compile rehearsal
 # (tests/test_tpu_compile.py and the fused tick's memory_analysis) with the
-# default RIT capacities: at 192x192 frames and a 16384-ray pool bucket the
-# fused tick takes 10.5 GB of the chip's 15.75 GB. The bucket is 3x the
+# default RIT block width: at 192x192 frames and a 16384-ray pool bucket the
+# fused tick takes 5.1 GB of the chip's 15.75 GB. The bucket is 3x the
 # largest window hole total (~5.2k rays) so no tick takes the dense
 # fallback. ray_chunk sets how many rays one staged chunk (the reference
 # prime on admission) sweeps the MVoxel table for; 16384 rather than the
@@ -108,8 +108,8 @@ class CompileClock:
 
 
 def _block_oracle(tables, ids, w):
-    """Per-(segment, MVoxel) block oracle: ``tables [B, P, C]`` (the block's
-    halo table), ``ids``/``w`` ``[B, 8, cap]`` → ``[B, C, cap]``."""
+    """Per-block oracle: ``tables [B, P, C]`` (each block's halo table),
+    ``ids``/``w`` ``[B, 8, T]`` → ``[B, C, T]``."""
     import jax
 
     from repro.nerf import grids
@@ -121,12 +121,15 @@ def _block_oracle(tables, ids, w):
         return jax.vmap(one)(tables, ids, w)
 
 
-def check_kernels(*, num_mv: int, channels: int, caps=(512, 1024),
-                  num_seg: int = 2, hidden: int = 64, samples: int = 4096,
+def check_kernels(*, num_mv: int, channels: int, block: int = 512,
+                  pages: int = 2, hidden: int = 64, samples: int = 4096,
                   interpret=None, seed: int = 0) -> dict:
     """Run each streaming kernel and ``fused_nerf_mlp`` once against its
     plain ``jnp`` oracle; returns the largest error relative to the
-    oracle's largest magnitude, per kernel."""
+    oracle's largest magnitude, per kernel. The ragged sweeps get
+    ``2 * num_mv`` blocks of ``block`` columns over random non-decreasing
+    keys, the last quarter of them dead; the stacked case keys ``pages``
+    pages of MVoxels as the mixed-scene path does."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -138,46 +141,42 @@ def check_kernels(*, num_mv: int, channels: int, caps=(512, 1024),
 
     p = 729  # (8 + 1)^3 halo rows of an 8^3-vertex MVoxel
     keys = jax.random.split(jax.random.PRNGKey(seed), 8)
-    mv_table = jax.random.normal(keys[0], (num_mv, p, channels))
-    mv_tables = jax.random.normal(keys[1], (num_seg, num_mv, p, channels))
+    n_blocks = 2 * num_mv
+    live = n_blocks - n_blocks // 4
 
-    def rit(key, cap):
-        k1, k2 = jax.random.split(key)
-        shape = (num_seg * num_mv, 8, cap)
+    def ragged(key, num_keys):
+        k0, k1, k2 = jax.random.split(key, 3)
+        block_key = jnp.sort(jax.random.randint(k0, (n_blocks,), 0,
+                                                num_keys, jnp.int32))
+        block_key = jnp.where(jnp.arange(n_blocks) < live, block_key,
+                              block_key[live - 1])
+        shape = (n_blocks, 8, block)
         ids = jax.random.randint(k1, shape, 0, p, jnp.int32)
         w = jax.random.uniform(k2, shape)
-        return ids, w / jnp.sum(w, axis=1, keepdims=True)
-
-    ih, wh = rit(keys[2], caps[0])
-    ir, wr = rit(keys[3], caps[1])
-    shared = jnp.tile(mv_table, (num_seg, 1, 1))       # block b → mv b % M
-    per_seg = mv_tables.reshape(num_seg * num_mv, p, channels)
+        return block_key, ids, w / jnp.sum(w, axis=1, keepdims=True)
 
     def rel_err(got, want):
         got, want = np.asarray(got), np.asarray(want)
         return float(np.max(np.abs(got - want)) / max(np.max(np.abs(want)),
                                                        1e-30))
 
+    def ragged_err(table, fn, key, num_keys):
+        block_key, ids, w = ragged(key, num_keys)
+        got = fn(table, block_key, jnp.array([live], jnp.int32), ids, w,
+                 interpret=interpret)
+        return rel_err(np.asarray(got)[:live],
+                       np.asarray(_block_oracle(table[block_key], ids,
+                                                w))[:live])
+
+    mv_table = jax.random.normal(keys[0], (num_mv, p, channels))
+    stacked = jax.random.normal(keys[1], (pages * num_mv, p, channels))
     errs = {}
-    errs["gather_trilerp_mvoxels_segmented"] = rel_err(
-        gt.gather_trilerp_mvoxels_segmented(mv_table, ih, wh, num_seg=num_seg,
-                                            interpret=interpret),
-        _block_oracle(shared, ih, wh))
-    errs["gather_trilerp_mvoxels_per_seg"] = rel_err(
-        gt.gather_trilerp_mvoxels_per_seg(mv_tables, ih, wh, num_seg=num_seg,
-                                          interpret=interpret),
-        _block_oracle(per_seg, ih, wh))
-    oh, orr = sp.fused_gather_dual(mv_table, ih, wh, ir, wr, num_seg=num_seg,
-                                   interpret=interpret)
-    errs["fused_gather_dual"] = max(
-        rel_err(oh, _block_oracle(shared, ih, wh)),
-        rel_err(orr, _block_oracle(shared, ir, wr)))
-    oh, orr = sp.fused_gather_dual_per_seg(mv_tables, ih, wh, ir, wr,
-                                           num_seg=num_seg,
-                                           interpret=interpret)
-    errs["fused_gather_dual_per_seg"] = max(
-        rel_err(oh, _block_oracle(per_seg, ih, wh)),
-        rel_err(orr, _block_oracle(per_seg, ir, wr)))
+    errs["gather_trilerp_mvoxels_segmented"] = ragged_err(
+        mv_table, gt.gather_trilerp_mvoxels_segmented, keys[2], num_mv)
+    errs["fused_gather_dual"] = ragged_err(mv_table, sp.fused_gather_dual,
+                                           keys[3], num_mv)
+    errs["fused_gather_dual (stacked pages)"] = ragged_err(
+        stacked, sp.fused_gather_dual, keys[7], pages * num_mv)
 
     dec = mlp.decoder_init(keys[4], mlp.DecoderCfg(
         mode="mlp", in_channels=channels, hidden=hidden))
@@ -432,14 +431,14 @@ def main(argv=None) -> int:
             f"pool_holes={cfg.pool_holes} slots={cfg.num_slots} "
             f"sessions={SMOKE['sessions']} frames/session={SMOKE['frames']} "
             f"window={cfg.window} stream_capacity={cfg.stream_capacity} "
-            f"(reference RIT capacity x2) ray_chunk={cfg.ray_chunk} "
+            f"(samples per RIT block) ray_chunk={cfg.ray_chunk} "
             f"pallas_interpret={cfg.resolved_pallas_interpret()}")
         log(f"cuts from the paper's setting: frames {PAPER['res']}x"
             f"{PAPER['res']} -> {cfg.res}x{cfg.res}; pool bucket adaptive "
             f"-> pinned at {cfg.pool_bucket} hole rays/session/window (one "
             f"tick compile); decoder MLP(64) -> direct (the only decoder a "
             f"baked scene serves); weights: baked analytic lego scene, not "
-            f"trained; RIT capacities: defaults (no cut)")
+            f"trained; RIT block width: default (no cut)")
         requests = make_requests(SMOKE["sessions"], SMOKE["frames"])
         r = serve_and_compare(cfg, requests)
         memory = r.pop("memory")
@@ -450,10 +449,15 @@ def main(argv=None) -> int:
             f"{stats.get('bytes_limit')}")
         for name, sizes in memory.items():
             log(f"compiled {name} program bytes: {json.dumps(sizes)}")
-        for stage, v in (r["rit_overflow"] or {}).items():
+        rit = dict(r["rit_overflow"] or {})
+        pad = rit.pop("pad", None)
+        for stage, v in rit.items():
             log(f"rit overflow share ({stage} stage): "
                 f"{v['overflow_share']:.6f} ({v['spilled_samples']} of "
-                f"{v['samples']} samples took the fallback gather)")
+                f"{v['samples']} samples spilled past the RIT)")
+        if pad is not None:
+            log(f"rit pad share: {pad['pad_share']:.6f} ({pad['pad_columns']}"
+                f" of {pad['columns']} columns of the live RIT blocks)")
         log(f"parity: min PSNR vs reference serve "
             f"{r['min_psnr_vs_reference_db']:.3f} dB (gate "
             f">= {PSNR_GATE_DB}); max hole-fraction diff "

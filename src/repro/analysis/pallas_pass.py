@@ -107,8 +107,16 @@ def record_launches(fn: Callable, *args, **kwargs) -> List[LaunchRecord]:
     path, line = _anchor(fn)
 
     def spy(kernel, *, grid=None, in_specs=None, out_specs=None,
-            out_shape=None, scratch_shapes=(), **_kw):
+            out_shape=None, scratch_shapes=(), grid_spec=None, **_kw):
+        prefetch = 0
+        if grid_spec is not None:  # PrefetchScalarGridSpec
+            prefetch = grid_spec.num_scalar_prefetch
+            grid, in_specs = grid_spec.grid, grid_spec.in_specs
+            out_specs = grid_spec.out_specs
+            scratch_shapes = grid_spec.scratch_shapes
+
         def launch(*operands):
+            operands = operands[prefetch:]  # scalars live in SMEM
             in_blocks = []
             for spec, op in zip(_as_seq(in_specs), operands):
                 bs = tuple(spec.block_shape)
@@ -195,17 +203,15 @@ def repo_launches() -> List[LaunchRecord]:
                                gather_trilerp, streaming_pipeline)
 
     recs: List[LaunchRecord] = []
-    # GU gather: [num_mv=4, P=832, C=4] halo table, 2 segments, cap 64
+    # GU gather: [num_mv=4, P=832, C=4] halo table, 8 ragged blocks of 64
+    ragged = (_sds((4, 832, 4)), _sds((8,), jnp.int32), _sds((1,), jnp.int32),
+              _sds((8, 8, 64), jnp.int32), _sds((8, 8, 64)))
     recs += record_launches(
-        gather_trilerp.gather_trilerp_mvoxels_segmented,
-        _sds((4, 832, 4)), _sds((8, 8, 64), jnp.int32), _sds((8, 8, 64)),
-        num_seg=2, interpret=True)
-    # fused dual-RIT streaming sweep: hole cap 64, reference cap 128
-    recs += record_launches(
-        streaming_pipeline.fused_gather_dual,
-        _sds((4, 832, 4)), _sds((8, 8, 64), jnp.int32), _sds((8, 8, 64)),
-        _sds((8, 8, 128), jnp.int32), _sds((8, 8, 128)),
-        num_seg=2, interpret=True)
+        gather_trilerp.gather_trilerp_mvoxels_segmented, *ragged,
+        interpret=True)
+    # the tick's merged hole + reference sweep: the same ragged kernel
+    recs += record_launches(streaming_pipeline.fused_gather_dual, *ragged,
+                            interpret=True)
     # fused NeRF MLP: 1024 samples, width 64, direnc 27, block 512
     h, dd = 64, 27
     recs += record_launches(

@@ -32,10 +32,10 @@ Design (the **flat ray-batch execution core**, :mod:`repro.core.raybatch`):
 * Hole handling uses **fixed-capacity compaction**: hole pixel indices are
   compacted (deterministic cumsum scatter, no ``nonzero``) into a static
   ``[hole_cap]`` ray batch per frame. A session whose window overflows the
-  capacity takes a dense re-render of its frames (the RIT-overflow
-  discipline) in isolation; its neighbours keep the sparse-path output
-  bit-for-bit. Per-session ``win_lens``/``caps`` are traced inputs, so
-  ragged windows batch into the same compiled program.
+  capacity takes a dense re-render of its frames in isolation; its
+  neighbours keep the sparse-path output bit-for-bit. Per-session
+  ``win_lens``/``caps`` are traced inputs, so ragged windows batch into
+  the same compiled program.
 
 * **Multi-device session sharding** (``RenderConfig.shard``): the flat
   layout is session-major, so laying a ``NamedSharding`` over the leading
@@ -47,7 +47,7 @@ Design (the **flat ray-batch execution core**, :mod:`repro.core.raybatch`):
   through the Pallas kernels end-to-end; the MVoxel halo table is built
   once per params (``prepare_streaming``) and broadcast across sessions,
   and the flat batch carries per-ray *segment ids* so the fused gather
-  keeps exclusive-run RIT capacity per session.
+  drops chunk padding from its ragged RIT.
 """
 from __future__ import annotations
 
@@ -131,7 +131,7 @@ class DeviceSparwEngine:
         self.ray_chunk = int(config.ray_chunk)
         # streaming backend: MVoxel table built once here, never per frame;
         # the flat core then tags every ray with its session segment so the
-        # fused gather keeps per-session RIT capacity
+        # gather drops chunk padding
         self.params = model.prepare_streaming(params)
         self._seg_aware = (getattr(model.cfg, "backend", "reference")
                            == "streaming"
@@ -250,11 +250,8 @@ class DeviceSparwEngine:
 
         Scope: the bit-parity guarantee covers the segment-oblivious
         (reference) backend, whose math is purely per-ray. The streaming
-        backend's RIT is built per chunk, so when ``quantum`` is not a
-        multiple of the chunk size a session's rays can straddle different
-        chunk boundaries at S=1 vs S=k and land in different
-        overflow-fallback sets; its contract is (and since PR 2 always
-        was) *numerical* parity with the reference path, not bitwise.
+        backend's RIT is built per chunk; its contract is *numerical*
+        parity with the reference path, not bitwise.
         """
         n = o.shape[0]
         c = min(self.ray_chunk, max(-(-quantum // 2), 1), n)

@@ -153,9 +153,10 @@ class StreamingTickResult(NamedTuple):
     #                           split on the fused path)
     next_rgb_ref: jnp.ndarray  # [S, H, W, 3] — tick t+1's reference frames
     next_dep_ref: jnp.ndarray  # [S, H, W]
-    # [2, 2] int32 — rows (hole stage, reference stage), columns (samples
-    # that spilled past their RIT bucket into the overflow fallback, live
-    # samples gathered); pooled padding rows are not counted
+    # [3, 2] int32 — rows 0 and 1 (hole stage, reference stage): (samples
+    # that spilled past the RIT, always 0 since the RIT is ragged; live
+    # samples gathered), pooled padding rows not counted; row 2: (pad
+    # columns, columns) in the live blocks of the tick's merged sweep
     rit_counts: jnp.ndarray
 
 
@@ -175,9 +176,9 @@ def render_tick_streaming(model, params: dict, cam: rays.Camera, *,
     render and hole fill as separate chunked programs — each ``lax.map``
     chunk re-streaming the full MVoxel table — this path bundles the
     pooled hole samples with the next reference's samples into one
-    dual-RIT sweep (``kernels.streaming_pipeline.gather_features_tick``),
-    so every (segment, MVoxel) halo block is fetched exactly once per
-    tick. The reference consumed here (``rgb_ref``/``dep_ref``, posed at
+    ragged-RIT sweep (``kernels.streaming_pipeline.gather_features_tick``),
+    so every MVoxel halo block is fetched at most once per tick. The
+    reference consumed here (``rgb_ref``/``dep_ref``, posed at
     ``ref_poses``) was produced by the *previous* tick (or by
     ``DeviceSparwEngine.prime_reference`` at trajectory start).
 
@@ -214,23 +215,15 @@ def render_tick_streaming(model, params: dict, cam: rays.Camera, *,
                                         c.near, c.far, ns, None)
     pts_r, t_r = rays.sample_along_rays(ref_batch.origins, ref_batch.dirs,
                                         c.near, c.far, ns, None)
-    scene_of_seg = params.get("scene_of_seg")
-    if scene_of_seg is not None:
-        # mixed-scene slot batch: every segment gathers from its own
-        # scene's page of the stacked resident set (traced map — scene
-        # churn re-steers this program without recompiling)
-        feats = streaming_pipeline.gather_features_tick_scenes(
-            params["table"], params["mv_table"], scene_of_seg,
-            model.streaming_cfg,
-            pts_h.reshape(-1, 3), jnp.repeat(hole_batch.seg, ns),
-            pts_r.reshape(-1, 3), jnp.repeat(ref_batch.seg, ns),
-            num_seg=s, interpret=c.pallas_interpret)
-    else:
-        feats = streaming_pipeline.gather_features_tick(
-            params["table"], params["mv_table"], model.streaming_cfg,
-            pts_h.reshape(-1, 3), jnp.repeat(hole_batch.seg, ns),
-            pts_r.reshape(-1, 3), jnp.repeat(ref_batch.seg, ns),
-            num_seg=s, interpret=c.pallas_interpret)
+    # a mixed-scene slot batch reads each segment's page of the stacked
+    # resident set through the traced segment→page map (scene churn
+    # re-steers this program without recompiling)
+    feats = streaming_pipeline.gather_features_tick(
+        params["mv_table"], model.streaming_cfg,
+        pts_h.reshape(-1, 3), jnp.repeat(hole_batch.seg, ns),
+        pts_r.reshape(-1, 3), jnp.repeat(ref_batch.seg, ns),
+        num_seg=s, scene_of_seg=params.get("scene_of_seg"),
+        interpret=c.pallas_interpret)
     sig_h, rgb_h = model.decode_features(
         params, feats.hole, jnp.repeat(hole_batch.dirs, ns, axis=0))
     sig_r, rgb_r = model.decode_features(
@@ -245,12 +238,14 @@ def render_tick_streaming(model, params: dict, cam: rays.Camera, *,
     valid = (jnp.arange(bucket)[None, :] < totals[:, None]).reshape(-1)
     sparse = scatter_segments(fill_col, flat_addr, valid,
                               s * n * hw).reshape(s, n, hw, 3)
-    with jax.named_scope("rit_fallback"):
-        hole_spill = feats.hole_overflow.reshape(-1, ns) & valid[:, None]
+    with jax.named_scope("rit_build"):
+        # the ragged RIT spills nothing: rows 0 and 1 count live samples
+        # beside a spill of 0; row 2 is the sweep's padding
+        zero = jnp.zeros((), jnp.int32)
         rit_counts = jnp.stack([
-            jnp.stack([jnp.sum(hole_spill), jnp.sum(valid) * ns]),
-            jnp.stack([jnp.sum(feats.ref_overflow),
-                       jnp.asarray(feats.ref_overflow.size)]),
+            jnp.stack([zero, jnp.sum(valid) * ns]),
+            jnp.stack([zero, jnp.asarray(feats.ref.shape[0])]),
+            jnp.stack([feats.pad_columns, feats.columns]),
         ]).astype(jnp.int32)
     overflowed = frame_over | (totals > pool_caps)
     fill = sparse

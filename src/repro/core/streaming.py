@@ -9,10 +9,10 @@ reordering).
 
 Pieces:
 * ``mvoxel_ids``          — sample → MVoxel assignment (base-corner rule).
-* ``build_rit``           — Ray Index Table: [num_mv, capacity] sample ids,
-                            capacity-padded; overflow falls back to the
-                            non-streaming path (mirrors the paper's NGP
-                            level-fallback).
+* ``build_rit``           — ragged Ray Index Table: samples sorted by
+                            MVoxel, each MVoxel's run cut into blocks of
+                            ``capacity`` columns; every sample streams,
+                            none falls back.
 * ``build_mvoxel_table``  — re-lays the vertex table as contiguous per-MVoxel
                             halo blocks [(edge+1)^3, C] — "vertex features
                             within one MVoxel stored continuously in DRAM".
@@ -34,7 +34,7 @@ from __future__ import annotations
 import functools as _functools
 from collections import OrderedDict
 from dataclasses import dataclass
-from typing import Dict, NamedTuple, Optional, Tuple
+from typing import Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -47,7 +47,7 @@ from repro.nerf import grids
 class StreamingCfg:
     grid_res: int = 64  # vertices per scene edge
     mvoxel_edge: int = 8  # vertices per MVoxel edge (paper: 8^3 points)
-    capacity: int = 512  # RIT entry capacity (samples per MVoxel)
+    capacity: int = 512  # samples per RIT block (an MVoxel may fill several)
     # on-chip layout of the staged halo block (paper §on-chip data layout):
     # "identity" keeps halo points x-major; "bank_interleaved" places each
     # point so the 8 corners of every voxel hit 8 distinct SRAM banks.
@@ -230,40 +230,64 @@ def build_mvoxel_table(table: jnp.ndarray, cfg: StreamingCfg) -> jnp.ndarray:
 
 
 class RIT(NamedTuple):
-    samples: jnp.ndarray  # [num_mv, capacity] int32 sample ids (-1 pad)
-    counts: jnp.ndarray  # [num_mv] int32
-    overflow: jnp.ndarray  # [S] bool — not covered (fallback path)
+    """Ragged Ray Index Table: samples in key (MVoxel) order, each key's
+    run cut into blocks of ``T`` columns (``T = cfg.capacity``)."""
+
+    block_key: jnp.ndarray  # [n_blocks] int32, non-decreasing
+    n_live: jnp.ndarray     # [1] int32 — blocks that hold samples
+    sample: jnp.ndarray     # [n_blocks, T] int32 sample per column (-1 pad)
+    col: jnp.ndarray        # [S] int32 column per sample (dropped: n_blocks*T)
+    live: jnp.ndarray       # [] int32 — samples the table holds
 
 
-def build_rit(mv: jnp.ndarray, cfg: StreamingCfg,
-              num_slots: Optional[int] = None) -> RIT:
-    """RIT over ``num_slots`` buckets (default: one per MVoxel).
+def rit_num_blocks(num_samples: int, num_keys: int, block: int) -> int:
+    """Static block count of a ragged RIT: the worst case, since each
+    non-empty key's run rounds up by less than one block and at most
+    ``min(num_keys, num_samples)`` keys are non-empty."""
+    return max(-(-num_samples // block) + min(num_keys, num_samples), 1)
 
-    The flat ray-batch core passes ``num_slots = num_seg * num_mvoxels``
-    with combined ``(segment, mvoxel)`` ids so every serving session keeps
-    its own per-MVoxel capacity inside ONE table build. Samples whose id is
-    ``>= num_slots`` (e.g. chunk-padding rays routed to the dump segment)
-    are dropped from the table entirely — they consume no capacity.
+
+def build_rit(key: jnp.ndarray, num_keys: int, block: int) -> RIT:
+    """Ragged RIT over ``num_keys`` keys (MVoxels, or ``page * num_mv +
+    mv`` over a stacked scene set), ``block`` columns per block.
+
+    One stable sort of (key, sample) puts the samples in key order; each
+    key's run fills ``ceil(count / block)`` consecutive blocks, so no
+    sample spills whatever piles into one MVoxel. Samples whose key is
+    ``>= num_keys`` (chunk-padding rays routed to the dump segment) are
+    dropped: they take no column. Blocks past ``n_live`` repeat the last
+    live key, so a kernel that walks the blocks fetches nothing new there.
     """
-    n_slots = cfg.num_mvoxels if num_slots is None else num_slots
-    s = mv.shape[0]
-    order = jnp.argsort(mv)  # the single global reorder
-    mv_sorted = jnp.sort(mv)
-    # first occurrence of each bucket id in the sorted sequence
-    starts = jnp.searchsorted(mv_sorted, jnp.arange(n_slots))
-    rank = jnp.arange(s) - starts[jnp.minimum(mv_sorted, n_slots - 1)]
-    in_range = mv_sorted < n_slots
-    keep = (rank < cfg.capacity) & in_range
-    slot = mv_sorted * cfg.capacity + jnp.minimum(rank, cfg.capacity - 1)
-    flat = jnp.full((n_slots * cfg.capacity,), -1, jnp.int32)
-    oob = n_slots * cfg.capacity  # dropped by mode="drop"
-    flat = flat.at[jnp.where(keep, slot, oob)].set(order.astype(jnp.int32),
-                                                   mode="drop")
-    # counts per bucket (clipped at capacity); out-of-range ids drop
-    counts_full = jnp.zeros((n_slots,), jnp.int32).at[mv].add(1, mode="drop")
-    counts = jnp.minimum(counts_full, cfg.capacity)
-    overflow = jnp.zeros((s,), bool).at[order].set(~keep & in_range)
-    return RIT(flat.reshape(n_slots, cfg.capacity), counts, overflow)
+    s = key.shape[0]
+    n_blocks = rit_num_blocks(s, num_keys, block)
+    key = jnp.minimum(key, num_keys).astype(jnp.int32)
+    key_sorted, order = jax.lax.sort(
+        (key, jnp.arange(s, dtype=jnp.int32)), num_keys=1, is_stable=True)
+    # key k's run in the sorted order is [start[k], start[k + 1])
+    start = jnp.searchsorted(
+        key_sorted, jnp.arange(num_keys + 1, dtype=jnp.int32)
+    ).astype(jnp.int32)
+    blocks = (start[1:] - start[:-1] + block - 1) // block
+    ends = jnp.cumsum(blocks, dtype=jnp.int32)
+    first = ends - blocks
+    n_live = ends[-1]
+    k = jnp.minimum(key_sorted, num_keys - 1)
+    col_sorted = jnp.where(
+        key_sorted < num_keys,
+        first[k] * block + jnp.arange(s, dtype=jnp.int32) - start[k],
+        n_blocks * block)
+    col = jnp.zeros((s,), jnp.int32).at[order].set(col_sorted,
+                                                   unique_indices=True)
+    b = jnp.arange(n_blocks, dtype=jnp.int32)
+    bk = jnp.searchsorted(ends, b, side="right").astype(jnp.int32)
+    block_key = jnp.minimum(bk, jnp.minimum(bk[jnp.maximum(n_live - 1, 0)],
+                                            num_keys - 1))
+    # sorted position of every column; past its key's run it is a pad
+    row = (start[block_key] + (b - first[block_key]) * block)[:, None] \
+        + jnp.arange(block, dtype=jnp.int32)[None, :]
+    held = row < start[block_key + 1][:, None]
+    sample = jnp.where(held, order[jnp.clip(row, 0, s - 1)], -1)
+    return RIT(block_key, n_live.reshape(1), sample, col, start[num_keys])
 
 
 def streaming_gather(table: jnp.ndarray, points: jnp.ndarray,

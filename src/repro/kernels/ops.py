@@ -8,7 +8,6 @@ from __future__ import annotations
 import functools
 from typing import Tuple
 
-import jax
 import jax.numpy as jnp
 
 from repro.core import streaming
@@ -33,9 +32,10 @@ def gather_features_streaming(table: jnp.ndarray, points: jnp.ndarray,
                               interpret: bool | None = None) -> jnp.ndarray:
     """Memory-centric feature gather of ``points`` from a dense vertex table.
 
-    Builds the RIT, runs the Pallas GU kernel per MVoxel, scatters results
-    back to sample order. RIT-overflow samples (capacity exceeded) take the
-    reference (non-streaming) path — the paper's fallback. Output matches
+    Builds the ragged RIT, runs the Pallas GU kernel over its blocks in
+    MVoxel order, and takes each sample's row back to sample order
+    (:func:`repro.kernels.streaming_pipeline.stream_gather`). Every sample
+    streams, however many pile into one MVoxel. Output matches
     ``grids.gather_trilerp_ref`` on the original table.
 
     ``mv_table`` is the per-MVoxel halo re-layout of ``table``; pass the
@@ -45,72 +45,29 @@ def gather_features_streaming(table: jnp.ndarray, points: jnp.ndarray,
 
     ``seg`` ([S] int32, with static ``num_seg``) is the flat ray-batch
     core's segment axis: samples from ``num_seg`` serving sessions share
-    this ONE gather call, but the RIT is bucketed per ``(segment, MVoxel)``
-    pair, so each session keeps exactly the per-MVoxel capacity (and
-    overflow-fallback set) its exclusive single-session run would have.
-    Samples with ``seg >= num_seg`` (chunk padding) are dropped from the
-    table — they consume no capacity and their output is unspecified.
+    this ONE gather call. Each output depends only on its own sample, so a
+    session's features are bit-identical to its exclusive run. Samples
+    with ``seg >= num_seg`` (chunk padding) are dropped from the table —
+    they take no column and read zero.
 
     ``scene_of_seg`` ([num_seg] int32, requires ``seg``) switches to the
-    mixed-scene path: ``table`` is the stacked resident set ``[K, res^3,
-    C]``, ``mv_table`` the stacked re-laid set ``[K, num_mv, P, C]``, and
-    each segment gathers from its own scene's rows (bit-identical per
-    segment to its exclusive single-scene run — the kernel body and the
-    fallback einsum are unchanged).
+    mixed-scene path: ``mv_table`` is the stacked re-laid set ``[K,
+    num_mv, P, C]``, and each segment gathers from its own scene's page
+    (``table`` is then unused).
     """
-    scened = scene_of_seg is not None
-    if scened and seg is None:
-        raise ValueError("scene_of_seg requires the seg array (the segment"
-                         "→scene map is indexed by segment id)")
-    s = points.shape[0]
-    if mv_table is None:
-        if scened:
+    if scene_of_seg is not None:
+        if seg is None:
+            raise ValueError("scene_of_seg requires the seg array (the "
+                             "segment→scene map is indexed by segment id)")
+        if mv_table is None:
             raise ValueError("mixed-scene gather needs the prebuilt stacked "
                              "mv_table [K, num_mv, P, C]")
+    if mv_table is None:
         mv_table = streaming.build_mvoxel_table(table, cfg)  # [M, P, C]
-    with jax.named_scope("rit_build"):
-        mv = streaming.mvoxel_ids(points, cfg)
-        num_mv = cfg.num_mvoxels
-        if seg is not None and (num_seg > 1 or scened):
-            # combined (segment, mvoxel) bucket id, segment-major; padding
-            # segments land out of range and drop out of the table build
-            bucket = jnp.where(seg < num_seg, seg * num_mv + mv,
-                               num_seg * num_mv)
-            num_slots = num_seg * num_mv
-        else:
-            bucket, num_slots = mv, num_mv
-        rit = streaming.build_rit(bucket, cfg, num_slots=num_slots)
-        local_ids, w = streaming.local_corner_ids(points, cfg)
-        # match the (possibly bank-interleaved) physical row order of mv_table
-        local_ids = streaming.remap_local_ids(local_ids, cfg)
-        # per-bucket sample blocks (RIT layout); padded columns use id 0 /
-        # weight 0
-        ids_mv, w_mv = _sp.rit_sample_blocks(local_ids, w, rit.samples)
-
-    with jax.named_scope("gather"):
-        if scened:
-            seg_tables = mv_table[scene_of_seg]  # [num_seg, num_mv, P, C]
-            out_mv = _gt.gather_trilerp_mvoxels_per_seg(
-                seg_tables, ids_mv, w_mv, num_seg=num_seg,
-                interpret=interpret)
-        elif seg is not None and num_seg > 1:
-            out_mv = _gt.gather_trilerp_mvoxels_segmented(
-                mv_table, ids_mv, w_mv, num_seg=num_seg, interpret=interpret)
-        else:
-            out_mv = _gt.gather_trilerp_mvoxels(mv_table, ids_mv, w_mv,
-                                                interpret=interpret)
-
-    feats = _sp.scatter_rit_outputs(out_mv, rit.samples, s)
-
-    # overflow fallback (pixel-centric path for the spilled samples)
-    if scened:
-        def gather(ids, weights):
-            scn = scene_of_seg[jnp.clip(seg, 0, num_seg - 1)]
-            return _sp.gather_trilerp_ref_scened(table, scn, ids, weights)
-    else:
-        def gather(ids, weights):
-            return _sp.fallback_gather(table, ids, weights)
-    return _sp.select_fallback(feats, rit.overflow, points, cfg, gather)
+    out, rit = _sp.stream_gather(_gt.gather_trilerp_mvoxels_segmented,
+                                 mv_table, points, cfg, seg, num_seg,
+                                 scene_of_seg, interpret=interpret)
+    return _sp.unpermute_rit_outputs(out, rit.col)
 
 
 # ---------------------------------------------------------------------------

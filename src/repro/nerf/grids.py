@@ -60,19 +60,6 @@ def gather_trilerp_ref(table: jnp.ndarray, ids: jnp.ndarray, weights: jnp.ndarra
                       precision=jax.lax.Precision.HIGHEST)
 
 
-def gather_trilerp_corners(gather_corner, weights: jnp.ndarray) -> jnp.ndarray:
-    """The same interpolation as :func:`gather_trilerp_ref`, as one
-    ``[S, C]`` gather per corner (``gather_corner(v)``) summed in corner
-    order. The streaming path's overflow fallback runs over every sample
-    of a tick; there a single ``table[ids]`` gather would materialize
-    ``[S, 8, C]``, which the TPU's tiled layout pads to 128 lanes on the
-    8-corner axis (16x the logical bytes)."""
-    acc = weights[:, 0:1] * gather_corner(0)
-    for v in range(1, 8):
-        acc = acc + weights[:, v:v + 1] * gather_corner(v)
-    return acc
-
-
 # ----------------------------------------------------------------------------
 # DenseGrid (DirectVoxGO)
 # ----------------------------------------------------------------------------

@@ -46,7 +46,7 @@ class NerfConfig:
     white_bkgd: bool = True
     backend: str = "reference"  # reference | streaming (Pallas hot path)
     stream_mvoxel_edge: int = 8  # paper: 8^3-point MVoxels
-    stream_capacity: int = 512  # RIT entry capacity (overflow -> fallback)
+    stream_capacity: int = 512  # samples per ragged RIT block
     # physical row order of the MVoxel halo blocks: "identity" keeps raw
     # (x,y,z) raster order (the parity control); "bank_interleaved" round-
     # robins halo points across SRAM banks so a voxel's 8 corners never
@@ -186,10 +186,11 @@ class NerfModel:
                        seg: Optional[jnp.ndarray] = None,
                        num_seg: int = 1) -> jnp.ndarray:
         """``seg``/``num_seg`` carry the flat ray-batch core's segment axis
-        (one segment per serving session): the streaming gather buckets its
-        RIT per (segment, MVoxel), so a fused cross-session batch keeps
-        exclusive-run capacity semantics. Ignored by reference paths (their
-        gathers are per-sample — segment-oblivious by construction).
+        (one segment per serving session): the streaming gather drops
+        chunk padding (``seg >= num_seg``) from its ragged RIT, and each
+        sample's output depends on that sample alone. Ignored by reference
+        paths (their gathers are per-sample — segment-oblivious by
+        construction).
 
         Mixed-scene serving rides the same call: when ``params`` carry the
         stacked resident set (``table`` ``[K, res^3, C]`` + ``mv_table``

@@ -306,9 +306,10 @@ class RenderServeEngine:
         self._rgb_ref: Optional[jnp.ndarray] = None
         self._dep_ref: Optional[jnp.ndarray] = None
         self._num_admission_ticks = 0  # ticks that ran a prime dispatch
-        # fused ticks' RIT spill: [stage (hole, ref), (spilled, gathered)]
-        # sample totals over every finalized tick (engine lifetime)
-        self._rit_counts = np.zeros((2, 2), np.int64)
+        # fused ticks' RIT counters over every finalized tick (engine
+        # lifetime): [stage (hole, ref), (spilled, gathered)] samples, then
+        # (pad columns, columns) of the ragged sweeps
+        self._rit_counts = np.zeros((3, 2), np.int64)
 
     # ------------------------------------------------------------------
     def _effective(self, sess: RenderSession) -> Tuple[int, int]:
@@ -902,9 +903,8 @@ class RenderServeEngine:
                     ticks_run, adm_ticks,
                     memory_metrics["staged_ref_sweeps"]) if fused
                 else memory_metrics["staged_table_sweeps_per_tick"])
-        # fused ticks' RIT overflow per stage: the share of live gather
-        # samples that spilled past their (segment, MVoxel) bucket and took
-        # the XLA fallback gather instead of the streaming kernel
+        # fused ticks' RIT per stage: the share of live gather samples that
+        # spilled past the RIT (0: it is ragged), and the sweep's padding
         rit_metrics = None
         if self.fused:
             rit = self._rit_counts - rit_start
@@ -913,6 +913,9 @@ class RenderServeEngine:
                         "samples": int(rit[i, 1]),
                         "overflow_share": float(rit[i, 0] / max(rit[i, 1], 1))}
                 for i, stage in enumerate(("hole", "ref"))}
+            rit_metrics["pad"] = {
+                "pad_columns": int(rit[2, 0]), "columns": int(rit[2, 1]),
+                "pad_share": float(rit[2, 0] / max(rit[2, 1], 1))}
         return {
             "ticks": self.num_ticks - start_ticks,
             "compiles": probe.recompiles(),

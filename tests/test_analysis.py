@@ -336,13 +336,16 @@ def test_pallas_spy_captures_real_kernel_geometry():
     recs = pallas_pass.record_launches(
         gather_trilerp.gather_trilerp_mvoxels_segmented,
         jax.ShapeDtypeStruct((4, 832, 4), jnp.float32),
+        jax.ShapeDtypeStruct((8,), jnp.int32),
+        jax.ShapeDtypeStruct((1,), jnp.int32),
         jax.ShapeDtypeStruct((8, 8, 64), jnp.int32),
         jax.ShapeDtypeStruct((8, 8, 64), jnp.float32),
-        num_seg=2, interpret=True)
+        interpret=True)
     assert len(recs) == 1
     rec = recs[0]
-    assert rec.grid == (4, 2)  # (num_mv, num_seg) — seg innermost
+    assert rec.grid == (8,)  # one step per ragged RIT block
     assert rec.in_blocks[0][0] == (1, 832, 4)  # one resident halo block
+    assert rec.in_blocks[1][0] == (1, 8, 64)  # scalar keys not counted
     assert not pallas_pass.check_launch(rec, "gather_trilerp.py")
 
 

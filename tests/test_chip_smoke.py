@@ -27,11 +27,11 @@ def smoke():
 
 
 def test_kernel_phase_matches_oracles(smoke):
-    errs = smoke.check_kernels(num_mv=8, channels=12, caps=(128, 256),
+    errs = smoke.check_kernels(num_mv=8, channels=12, block=128,
                                samples=1024, interpret=True)
     assert set(errs) == {
-        "gather_trilerp_mvoxels_segmented", "gather_trilerp_mvoxels_per_seg",
-        "fused_gather_dual", "fused_gather_dual_per_seg", "fused_nerf_mlp"}
+        "gather_trilerp_mvoxels_segmented", "fused_gather_dual",
+        "fused_gather_dual (stacked pages)", "fused_nerf_mlp"}
     assert all(e <= 1e-5 for e in errs.values()), errs
 
 
@@ -57,7 +57,11 @@ def test_serving_phase_queues_and_matches_reference(smoke):
     assert r["warm_compile_s"] == 0.0
     ref = r["rit_overflow"]["ref"]
     assert ref["samples"] == 4 * 2 * 32 * 32 * 8  # ticks x slots x HW x ns
-    assert 0.0 <= ref["overflow_share"] <= 1.0
+    # the ragged RIT spills nothing; its padding is counted
+    assert ref["overflow_share"] == 0.0
+    pad = r["rit_overflow"]["pad"]
+    assert 0 <= pad["pad_columns"] < pad["columns"]
+    assert pad["pad_share"] == pad["pad_columns"] / pad["columns"]
     assert set(r["memory"]) == {"device_after_fused_serve", "fused_tick",
                                 "prime"}
     assert r["memory"]["fused_tick"]["argument"] > 0

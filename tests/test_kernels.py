@@ -31,12 +31,13 @@ def test_gather_trilerp_shapes(res, edge, cap, n, c):
                                atol=2e-5, rtol=1e-5)
 
 
-def test_gather_trilerp_overflow_fallback():
-    """Samples past RIT capacity take the reference path — still exact."""
+def test_gather_trilerp_piled_mvoxel_exact():
+    """64 samples piled into one MVoxel at 8 columns per RIT block fill 8
+    blocks of the streaming kernel — still exact."""
     cfg = streaming.StreamingCfg(grid_res=32, mvoxel_edge=8, capacity=8)
     table = jax.random.normal(jax.random.key(0), (32**3, 4))
     pts = jnp.concatenate([
-        jnp.zeros((64, 3)) + 0.01,  # overflow one mvoxel
+        jnp.zeros((64, 3)) + 0.01,  # pile into one mvoxel
         jax.random.uniform(jax.random.key(1), (200, 3), minval=-1, maxval=1),
     ])
     got = ops.gather_features_streaming(table, pts, cfg)

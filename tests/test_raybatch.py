@@ -104,9 +104,9 @@ def test_scatter_segments_drops_invalid():
 
 
 def test_segmented_streaming_gather_matches_per_segment(scene):
-    """The (segment, MVoxel)-bucketed fused gather returns exactly what
-    per-segment gather calls return — per-session RIT capacity survives
-    cross-session fusion."""
+    """The cross-session gather returns exactly what per-segment gather
+    calls return: a sample's output does not depend on the other
+    sessions' samples that share its ragged RIT blocks."""
     from repro.core import streaming
     from repro.kernels import ops
 
@@ -132,13 +132,13 @@ def test_segmented_streaming_gather_matches_per_segment(scene):
 
 
 def test_dump_segment_consumes_no_capacity(scene):
-    """Chunk-padding rays (seg == num_seg) must not steal RIT capacity:
-    a real segment's output is unchanged by appended dump-segment points."""
+    """Chunk-padding rays (seg == num_seg) take no RIT column: a real
+    segment's output is unchanged by appended dump-segment points."""
     from repro.kernels import ops
 
     model, _ = models.make_model("dvgo", grid_res=32, channels=4,
                                  decoder="direct", backend="streaming",
-                                 stream_capacity=16)  # tiny: overflow matters
+                                 stream_capacity=16)  # tiny: piles fill blocks
     params = model.prepare_streaming(model.init_baked(scene))
     cfg = model.streaming_cfg
     rng = np.random.RandomState(3)

@@ -31,26 +31,89 @@ def test_streaming_gather_exact(table, pts):
     assert np.all(np.diff(mv[np.asarray(order)]) >= 0)
 
 
+def _check_ragged(rit, key, num_keys, block):
+    """Structural checks of a ragged RIT against its keys [S]."""
+    key = np.asarray(key)
+    sample = np.asarray(rit.sample)
+    col = np.asarray(rit.col)
+    block_key = np.asarray(rit.block_key)
+    n_blocks = sample.shape[0]
+    n_live = int(rit.n_live[0])
+    live = key < num_keys
+    # the static bound holds and the keys never go back
+    assert n_blocks == streaming.rit_num_blocks(key.size, num_keys, block)
+    assert n_live <= n_blocks
+    assert np.all(np.diff(block_key) >= 0)
+    assert np.all(block_key[n_live:] == block_key[max(n_live - 1, 0)])
+    # every live sample has exactly one column, in a block of its key
+    flat = sample.reshape(-1)
+    held = flat[flat >= 0]
+    assert len(np.unique(held)) == len(held) == int(live.sum())
+    assert int(rit.live) == int(live.sum())
+    assert np.array_equal(np.sort(held), np.flatnonzero(live))
+    assert np.all(flat[col[live]] == np.flatnonzero(live))
+    assert np.all(block_key[col[live] // block] == key[live])
+    # dropped samples take none; only live blocks hold samples
+    assert np.all(col[~live] == n_blocks * block)
+    assert np.all(sample[n_live:] == -1)
+    # each live block holds at least one sample (whole runs, pads at ends)
+    assert np.all((sample[:n_live] >= 0).any(axis=1))
+
+
 def test_rit_covers_every_sample_once(pts):
     mv = streaming.mvoxel_ids(pts, CFG)
-    rit = streaming.build_rit(mv, CFG)
-    vals = np.asarray(rit.samples)
-    kept = vals[vals >= 0]
-    assert len(np.unique(kept)) == len(kept)
-    assert len(kept) + int(rit.overflow.sum()) == pts.shape[0]
-    # every RIT row only holds samples of its own mvoxel
+    rit = streaming.build_rit(mv, CFG.num_mvoxels, CFG.capacity)
+    _check_ragged(rit, mv, CFG.num_mvoxels, CFG.capacity)
+    assert int(rit.live) == pts.shape[0]
+    # every block only holds samples of its own mvoxel
+    vals = np.asarray(rit.sample)
     mv_np = np.asarray(mv)
-    for row in range(0, CFG.num_mvoxels, 37):
-        s = vals[row][vals[row] >= 0]
-        assert np.all(mv_np[s] == row)
+    for b in range(int(rit.n_live[0])):
+        s = vals[b][vals[b] >= 0]
+        assert np.all(mv_np[s] == int(rit.block_key[b]))
 
 
 def test_rit_capacity_overflow():
-    pts = jnp.zeros((100, 3))  # all samples in one voxel
+    """A bucket of 100 samples piled in one voxel at 16 columns per block
+    fills 7 blocks instead of spilling: no sample is left out."""
+    pts = jnp.zeros((100, 3))
     cfg = streaming.StreamingCfg(grid_res=48, mvoxel_edge=8, capacity=16)
-    rit = streaming.build_rit(streaming.mvoxel_ids(pts, cfg), cfg)
-    assert int(rit.overflow.sum()) == 100 - 16
-    assert int(rit.counts.max()) == 16
+    mv = streaming.mvoxel_ids(pts, cfg)
+    rit = streaming.build_rit(mv, cfg.num_mvoxels, cfg.capacity)
+    _check_ragged(rit, mv, cfg.num_mvoxels, cfg.capacity)
+    assert int(rit.n_live[0]) == 7
+    assert int((np.asarray(rit.sample) >= 0).sum()) == 100
+    assert int((np.asarray(rit.sample[6]) >= 0).sum()) == 100 - 6 * 16
+    # and the kernel gathers every one of them exactly
+    from repro.kernels import ops
+
+    pts = pts + jax.random.uniform(jax.random.key(5), (100, 3),
+                                   minval=-0.02, maxval=0.0)
+    assert np.all(np.asarray(streaming.mvoxel_ids(pts, cfg)) == int(mv[0]))
+    table = jax.random.normal(jax.random.key(6), (cfg.grid_res**3, 4))
+    got = ops.gather_features_streaming(table, pts, cfg, interpret=True)
+    ids, w = grids.corner_ids_weights(pts, cfg.grid_res)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(
+        grids.gather_trilerp_ref(table, ids, w)), atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("num_keys,block,dropped", [
+    (27, 16, 0.0), (27, 16, 0.3), (216, 8, 0.5), (8, 64, 0.9), (64, 4, 1.0),
+])
+def test_rit_drops_padding_and_keeps_its_bound(num_keys, block, dropped):
+    """Keys past ``num_keys`` (chunk padding) take no column, ``block_key``
+    never decreases and ``n_blocks`` is the static worst case, over random
+    keys, piled keys and all-dropped streams."""
+    rng = np.random.RandomState(num_keys + block)
+    s = 700
+    key = rng.randint(0, num_keys, size=s)
+    key[: s // 5] = rng.randint(0, 2)  # a pile on one or two keys
+    key[rng.uniform(size=s) < dropped] = num_keys + rng.randint(0, 3)
+    rit = streaming.build_rit(jnp.asarray(key, jnp.int32), num_keys, block)
+    _check_ragged(rit, key, num_keys, block)
+    blocks = sum(-(-int(c) // block) for c in np.bincount(
+        key[key < num_keys], minlength=num_keys))
+    assert int(rit.n_live[0]) == blocks
 
 
 def test_mvoxel_table_halo_equivalence(table, pts):

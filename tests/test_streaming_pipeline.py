@@ -90,18 +90,22 @@ def test_layout_bit_identical_staged_gather(table, pts):
 
 
 def test_fused_gather_matches_reference_both_sets(table, pts):
+    """The tick's merged stream returns both sets equal to the reference
+    gather, and its padding counts close: pads + live samples = columns."""
     seg = jnp.concatenate([jnp.zeros(300, jnp.int32),
                            jnp.ones(300, jnp.int32)])
     ids, w = grids.corner_ids_weights(pts, CFG_I.grid_res)
     ref = np.asarray(grids.gather_trilerp_ref(table, ids, w))
     for cfg in (CFG_I, CFG_B):
         mv = streaming.build_mvoxel_table(table, cfg)
-        fh, fr, _, _ = streaming_pipeline.gather_features_tick(
-            table, mv, cfg, pts, seg, pts, seg, num_seg=2, interpret=True)
+        fh, fr, pad, cols = streaming_pipeline.gather_features_tick(
+            mv, cfg, pts, seg, pts, seg, num_seg=2, interpret=True)
         np.testing.assert_allclose(np.asarray(fh), ref, atol=1e-5,
                                    rtol=1e-5)
         np.testing.assert_allclose(np.asarray(fr), ref, atol=1e-5,
                                    rtol=1e-5)
+        assert int(cols) % cfg.capacity == 0
+        assert int(cols) - int(pad) == 2 * pts.shape[0]
 
 
 def test_fused_gather_layout_bit_identical(table, pts):
@@ -110,29 +114,83 @@ def test_fused_gather_layout_bit_identical(table, pts):
     for cfg in (CFG_I, CFG_B):
         mv = streaming.build_mvoxel_table(table, cfg)
         outs.append(streaming_pipeline.gather_features_tick(
-            table, mv, cfg, pts, seg, pts, seg, num_seg=1, interpret=True))
+            mv, cfg, pts, seg, pts, seg, num_seg=1, interpret=True))
     np.testing.assert_array_equal(np.asarray(outs[0][0]),
                                   np.asarray(outs[1][0]))
     np.testing.assert_array_equal(np.asarray(outs[0][1]),
                                   np.asarray(outs[1][1]))
 
 
-def test_fused_gather_ref_set_capacity_scales(table, pts):
-    # the reference set's RIT capacity is ref_cap_factor * capacity —
-    # visible as a larger per-bucket block, and overflow falls back
-    # exactly (outputs still match the reference gather)
-    small = dataclasses.replace(CFG_I, capacity=32)
+def test_fused_gather_piled_bucket_matches_reference(table, pts):
+    """100 samples piled into one MVoxel at 16 columns per block fill 7
+    blocks of the merged stream; both sets still match the reference
+    gather, and dropped padding (seg == num_seg) takes no column."""
+    small = dataclasses.replace(CFG_I, capacity=16)
     mv = streaming.build_mvoxel_table(table, small)
-    seg = jnp.zeros(pts.shape[0], jnp.int32)
-    ids, w = grids.corner_ids_weights(pts, small.grid_res)
+    piled = jnp.concatenate([jnp.full((100, 3), 0.1), pts[:50]])
+    seg = jnp.zeros(piled.shape[0], jnp.int32)
+    dump = jnp.full(piled.shape[0], 1, jnp.int32)
+    ids, w = grids.corner_ids_weights(piled, small.grid_res)
     ref = np.asarray(grids.gather_trilerp_ref(table, ids, w))
-    fh, fr, spill_h, spill_r = streaming_pipeline.gather_features_tick(
-        table, mv, small, pts, seg, pts, seg, num_seg=1, ref_cap_factor=4,
-        interpret=True)
+    fh, fr, pad, cols = streaming_pipeline.gather_features_tick(
+        mv, small, piled, seg, piled, seg, num_seg=1, interpret=True)
     np.testing.assert_allclose(np.asarray(fh), ref, atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(np.asarray(fr), ref, atol=1e-5, rtol=1e-5)
-    # the same samples spill less from the 4x larger reference buckets
-    assert int(spill_r.sum()) < int(spill_h.sum())
+    # the pile is 200 samples of one MVoxel in the merged stream: 13 blocks
+    key = streaming.mvoxel_ids(jnp.concatenate([piled, piled]), small)
+    rit = streaming.build_rit(key, small.num_mvoxels, small.capacity)
+    assert int(jnp.sum(rit.block_key[:int(rit.n_live[0])] == key[0])) >= 13
+    assert int(cols) == int(rit.n_live[0]) * 16
+    # the reference set dropped: its rows read zero, the holes' are kept
+    fh2, fr2, pad2, cols2 = streaming_pipeline.gather_features_tick(
+        mv, small, piled, seg, piled, dump, num_seg=1, interpret=True)
+    np.testing.assert_array_equal(np.asarray(fh2), np.asarray(fh))
+    assert not np.asarray(fr2).any()
+    assert int(cols2) - int(pad2) == piled.shape[0]
+
+
+def test_sample_output_independent_of_its_block_mates(table, pts):
+    """A sample's features are bit-identical whatever samples share its
+    RIT block: alone, beside the other half of the set, or with a pile in
+    its MVoxel that moves it to another block and column."""
+    cfg = dataclasses.replace(CFG_I, capacity=16)
+    mv = streaming.build_mvoxel_table(table, cfg)
+    probe = pts[:40]
+
+    def feats(extra):
+        both = jnp.concatenate([probe, extra])
+        f = ops.gather_features_streaming(table, both, cfg, mv_table=mv,
+                                          interpret=True)
+        return np.asarray(f[:40])
+
+    alone = feats(jnp.zeros((0, 3)))
+    np.testing.assert_array_equal(alone, feats(pts[40:]))
+    np.testing.assert_array_equal(alone, feats(jnp.repeat(probe, 3, 0)))
+    np.testing.assert_array_equal(alone, feats(probe[::-1]))
+
+
+def test_mixed_scene_tick_reads_each_segments_page(table, pts):
+    """On the mixed-scene path the merged stream keys (page, MVoxel) over
+    the stacked table: each segment's rows equal its own scene's
+    exclusive gather, bit for bit."""
+    other = table[::-1] * 0.5
+    mv_a = streaming.build_mvoxel_table(table, CFG_I)
+    mv_b = streaming.build_mvoxel_table(other, CFG_I)
+    stacked = jnp.stack([mv_a, mv_b])
+    seg = jnp.concatenate([jnp.zeros(300, jnp.int32),
+                           jnp.ones(300, jnp.int32)])
+    scene_of_seg = jnp.array([1, 0], jnp.int32)
+    fh, fr, _, _ = streaming_pipeline.gather_features_tick(
+        stacked, CFG_I, pts, seg, pts[::-1], seg, num_seg=2,
+        scene_of_seg=scene_of_seg, interpret=True)
+    for got, p, scene in ((fh[:300], pts[:300], mv_b),
+                          (fh[300:], pts[300:], mv_a),
+                          (fr[:300], pts[::-1][:300], mv_b),
+                          (fr[300:], pts[::-1][300:], mv_a)):
+        alone = ops.gather_features_streaming(None, p, CFG_I,
+                                              mv_table=scene,
+                                              interpret=True)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(alone))
 
 
 # ---------------------------------------------------------------------------
@@ -220,16 +278,22 @@ def test_tick_memory_stats_sweep_math(tick_setup):
 
 
 def test_tick_traffic_analytic_counts():
-    traffic = streaming_pipeline.tick_traffic(CFG_I, channels=4, num_seg=2,
-                                              cap_hole=128, cap_ref=256)
+    samples = 2 * (128 + 256) * 8
+    traffic = streaming_pipeline.tick_traffic(CFG_I, channels=4,
+                                              samples=samples)
     num_mv = CFG_I.num_mvoxels
     assert traffic["mvoxel_table_sweeps"] == 1.0
     assert traffic["mvoxel_table_bytes"] == num_mv * CFG_I.halo_rows * 4 * 4
-    # RIT side: ids+weights in, features out, for both stages' blocks
-    per_slot = (128 + 256) * 8 * 8 + (128 + 256) * 4 * 4
-    assert traffic["rit_bytes"] == 2 * num_mv * per_slot
+    # RIT side: ids+weights in, features out, per column of the ragged
+    # RIT's static block bound (ceil(samples / T) + num_mv blocks)
+    blocks = -(-samples // CFG_I.capacity) + num_mv
+    assert traffic["rit_bytes"] == blocks * CFG_I.capacity * (8 * 8 + 4 * 4)
     assert traffic["total_bytes"] == \
         traffic["mvoxel_table_bytes"] + traffic["rit_bytes"]
+    # the bound grows with the samples, not with segments x MVoxels x caps
+    more = streaming_pipeline.tick_traffic(CFG_I, channels=4,
+                                           samples=4 * samples)
+    assert more["rit_bytes"] < 4 * traffic["rit_bytes"]
 
 
 # ---------------------------------------------------------------------------
