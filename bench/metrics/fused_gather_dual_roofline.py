@@ -1,7 +1,8 @@
 """Kernels: the fused dual MVoxel gather's share of its roofline, in
 percent: the least time the chip could take for the gather the window's
-ticks require (``work.gather_work``: every live hole and reference sample,
-one table sweep per tick) over the kernel's device time in the trace."""
+ticks require (the architecture's ``gather_work``: every live hole and
+reference sample, one table sweep per tick) over the kernel's device time
+in the trace."""
 import peaks
 
 

@@ -1,8 +1,8 @@
 """Kernels: the fused MLP decoder's share of its roofline, in percent:
 the least time the chip could take to decode every live hole and
-reference sample of the window's ticks (``work.mlp_work``) over the
-kernel's device time in the trace. Nothing to read with a direct
-decoder."""
+reference sample of the window's ticks (the architecture's
+``decoder_work``) over the kernel's device time in the trace. Nothing to
+read with a direct decoder."""
 import peaks
 
 

@@ -2,18 +2,19 @@
 
 Written from the method (Cicero, arXiv 2404.11852, section III) and the
 serving contract, in straightforward ``jax.numpy``; it imports nothing of
-the program under test and reads only the benchmark's own weights
-(``weights.py``) and poses. For one session window of ``N`` target poses:
+the program under test and reads only the benchmark's own weights and
+poses. The model's field (its density and colour at a point seen from a
+direction) is the architecture's, ``field`` of ``bench/arch/<arch>.py``;
+the rest is shared. For one session window of ``N`` target poses:
 
 1. the reference pose: the window's first pose for a session's first
    window, otherwise extrapolated from the session's last two poses
    ``N/2`` frame intervals ahead (rotation on SO(3) by log/exp,
    translation linearly);
 2. the reference frame: every pixel's ray rendered by the volume
-   renderer (uniform samples between ``near`` and ``far``, trilinear
-   interpolation of the dense table, the decoder, alpha compositing over
-   a white background, depth as the weighted sample distance plus the
-   far plane for the transmitted remainder);
+   renderer (uniform samples between ``near`` and ``far``, the field at
+   each, alpha compositing over a white background, depth as the weighted
+   sample distance plus the far plane for the transmitted remainder);
 3. each target frame: the reference frame unprojected by its depth,
    moved into the target camera, rounded to the nearest pixel and
    z-buffered (the nearest depth wins; among candidates within 1e-3 of
@@ -23,8 +24,9 @@ the program under test and reads only the benchmark's own weights
    it;
 4. holes rendered as in step 2 from the target pose.
 
-Every contraction goes through :func:`contract` (the trilinear corner sum
-through :func:`multiply`), whose ``precision`` is
+Every contraction goes through :func:`contract` (an elementwise product
+that a field sums, such as a trilinear corner sum, through
+:func:`multiply`), whose ``precision`` is
 ``"highest"`` (float32) for the reference or ``"high"`` (three bfloat16
 passes, emulated so that every platform computes the same thing) for the
 benchmark's control.
@@ -150,50 +152,17 @@ def reference_pose(poses: Sequence[np.ndarray], start: int, window: int,
 # ---------------------------------------------------------------------------
 
 
-def _trilinear(table, pts, grid_res: int, precision):
-    g = jnp.clip((pts + 1.0) * 0.5 * (grid_res - 1), 0.0, grid_res - 1 - 1e-4)
-    base = jnp.floor(g).astype(jnp.int32)
-    frac = g - base
-    corners = np.array([[i, j, k] for i in (0, 1) for j in (0, 1)
-                        for k in (0, 1)], np.int32)
-    c = jnp.clip(base[:, None, :] + corners[None], 0, grid_res - 1)
-    ids = (c[..., 0] * grid_res + c[..., 1]) * grid_res + c[..., 2]
-    w = jnp.where(corners[None] == 1, frac[:, None, :],
-                  1.0 - frac[:, None, :]).prod(axis=-1)
-    # one [S, C] gather per corner, summed in corner order (a stacked
-    # [S, 8, C] gather pads the 8-corner axis to 128 lanes on a TPU)
-    acc = multiply(w[:, 0:1], table[ids[:, 0]], precision)
-    for v in range(1, 8):
-        acc = acc + multiply(w[:, v:v + 1], table[ids[:, v]], precision)
-    return acc
-
-
-def _decode(dec, feats, dirs, precision):
-    if not dec:
-        return jnp.maximum(feats[:, 0], 0.0), jnp.clip(feats[:, 1:4], 0.0, 1.0)
-    mm = partial(contract, "sc,ch->sh", precision=precision)
-    h = jax.nn.relu(mm(feats, dec["w1"]) + dec["b1"])
-    h = jax.nn.relu(mm(h, dec["w2"]) + dec["b2"])
-    sigma = jax.nn.softplus(mm(h, dec["w_sigma"]))[:, 0]
-    x, y, z = dirs[:, 0:1], dirs[:, 1:2], dirs[:, 2:3]
-    enc = jnp.concatenate([dirs, x * y, y * z, x * z, x * x, y * y, z * z], -1)
-    rgb = jax.nn.sigmoid(mm(jnp.concatenate([h, enc], -1), dec["w_rgb"])
-                         + dec["b_rgb"])
-    return sigma, rgb
-
-
-@partial(jax.jit, static_argnames=("model", "precision"))
-def render_rays(weights, origins, dirs, *, model, precision):
-    """Colors ``[R, 3]`` and depths ``[R]`` of the rays ``origins``/``dirs``.
-    ``model`` is ``(grid_res, num_samples, near, far)``."""
-    grid_res, ns, near, far = model
+@partial(jax.jit, static_argnames=("field", "key", "samples", "precision"))
+def render_rays(weights, origins, dirs, *, field, key, samples, precision):
+    """Colors ``[R, 3]`` and depths ``[R]`` of the rays ``origins``/``dirs``
+    through the architecture's ``field`` (its static part ``key``).
+    ``samples`` is ``(num_samples, near, far)``."""
+    ns, near, far = samples
     t = jnp.broadcast_to(jnp.linspace(near, far, ns, dtype=jnp.float32),
                          (origins.shape[0], ns))
     pts = origins[:, None, :] + dirs[:, None, :] * t[..., None]
-    feats = _trilinear(weights["table"], pts.reshape(-1, 3), grid_res,
-                       precision)
-    sigma, rgb = _decode(weights["decoder"], feats,
-                         jnp.repeat(dirs, ns, axis=0), precision)
+    sigma, rgb = field(weights, pts.reshape(-1, 3),
+                       jnp.repeat(dirs, ns, axis=0), key, precision)
     sigma, rgb = sigma.reshape(-1, ns), rgb.reshape(-1, ns, 3)
     delta = jnp.diff(t, axis=-1)
     delta = jnp.concatenate([delta, delta[:, -1:]], axis=-1)
@@ -217,10 +186,11 @@ def frame_rays(u, v, pose, *, precision):
     return jnp.broadcast_to(pose[:3, 3], d.shape), d
 
 
-def render(weights, origins: np.ndarray, dirs: np.ndarray, model,
+def render(weights, origins: np.ndarray, dirs: np.ndarray, model: dict,
            precision: str, chunk: int) -> Tuple[np.ndarray, np.ndarray]:
     """Render rays in chunks of ``chunk`` (the last one padded), so that
-    every call has one shape."""
+    every call has one shape. ``model`` holds :func:`render_rays`'s static
+    arguments but ``precision``."""
     n = len(origins)
     if n == 0:
         return np.zeros((0, 3), np.float32), np.zeros((0,), np.float32)
@@ -230,7 +200,7 @@ def render(weights, origins: np.ndarray, dirs: np.ndarray, model,
     cols, deps = [], []
     for i in range(0, len(o), chunk):
         col, dep = render_rays(weights, jnp.asarray(o[i:i + chunk]),
-                               jnp.asarray(d[i:i + chunk]), model=model,
+                               jnp.asarray(d[i:i + chunk]), **model,
                                precision=precision)
         cols.append(np.asarray(col))
         deps.append(np.asarray(dep))
@@ -295,19 +265,24 @@ def warp(rgb_ref, dep_ref, pose_ref, pose_tgt, *, res, focal, precision):
 # ---------------------------------------------------------------------------
 
 
-def model_key(cfg: dict) -> tuple:
-    return (cfg["grid_res"], cfg["num_samples"], float(cfg["near"]),
-            float(cfg["far"]))
+def model_key(arch, cfg: dict) -> dict:
+    """:func:`render_rays`'s static arguments for a model of the
+    architecture ``arch`` (``bench/arch/<arch>.py``)."""
+    return {"field": arch.field, "key": arch.reference_key(cfg),
+            "samples": (cfg["num_samples"], float(cfg["near"]),
+                        float(cfg["far"]))}
 
 
-def render_window(weights, cfg: dict, cam: Camera, poses: List[np.ndarray],
-                  start: int, count: int, precision: str) -> Dict[str, object]:
-    """Reference frames of one session window: frames ``start .. start +
-    count - 1`` of a session whose poses are ``poses``. Returns the
+def render_window(arch, weights, cfg: dict, cam: Camera,
+                  poses: List[np.ndarray], start: int, count: int,
+                  precision: str) -> Dict[str, object]:
+    """Reference frames of one session window of a model of the
+    architecture ``arch``: frames ``start .. start + count - 1`` of a
+    session whose poses are ``poses``. Returns the
     reference frame (``ref_rgb [HW, 3]``, ``ref_depth [HW]``), the target
     frames ``frames [count, HW, 3]``, their hole counts and each frame's
     settled hole pixels (``settled``, a list of pixel indices)."""
-    model = model_key(cfg)
+    model = model_key(arch, cfg)
     chunk = min(CHUNK_RAYS, cam.res * cam.res)
     pose_ref = reference_pose(poses, start, cfg["window"], precision)
     ref_rgb, ref_dep = render(weights, *pixel_rays(cam, pose_ref, precision),

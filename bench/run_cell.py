@@ -5,13 +5,16 @@
 
 A cell (``workloads`` in ``BENCHMARK.json``) names a configuration
 (``bench/configs/<config>.json``) and a traffic mix
-(``bench/traffic/<mix>.json``). One process serves one run:
+(``bench/traffic/<mix>.json``). The configuration names its architecture
+(``"arch"``), whose module ``bench/arch/<arch>.py`` makes the weights,
+builds the program's model, and gives the reference its field and the
+work counts their per-sample terms. One process serves one run:
 
 1. It refuses a backend other than the TPU, Pallas in interpret mode, and
    fewer chips than the cell asks for: it exits non-zero and prints no
    result.
-2. Set-up: the weights are made on the device from the seed
-   (``weights.py``), the serving engine is built as
+2. Set-up: the weights are made on the device from the seed (the
+   architecture's ``make_weights``), the serving engine is built as
    ``repro.api.make_renderer(cfg).pipeline.serve_engine_for(cfg)`` builds
    it, and the first viewers' sessions are admitted and served one tick,
    which primes the engine and warms every program and shape the window
@@ -45,6 +48,7 @@ import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -73,16 +77,25 @@ def log(msg: str) -> None:
 # ---------------------------------------------------------------------------
 
 
-def load_cell(name: str, benchmark: Optional[dict] = None) -> dict:
+def load_cell(name: str, benchmark: Optional[dict] = None,
+              root: Path = ROOT) -> dict:
     """The cell ``name``: its workload entry, configuration, traffic mix,
-    limits and the metric entries it reports."""
+    limits and the metric entries it reports. ``benchmark`` is
+    ``BENCHMARK.json``'s content, read from ``root`` when not given; the
+    data files it names (configurations, and each cell's mix and limits
+    under ``bench/``) are read under ``root``. The configuration's
+    architecture module is loaded, so that a configuration without one
+    fails here."""
     if benchmark is None:
-        benchmark = json.loads((ROOT / "BENCHMARK.json").read_text())
+        benchmark = json.loads((root / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in benchmark["workloads"]}
     if name not in cells:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json")
     cell = cells[name]
     configs = {c["name"]: c for c in benchmark["configs"]}
+    config = json.loads((root / configs[cell["config"]]["file"]).read_text())
+    load_arch(config)
+    data = root / BENCH.name
 
     def applies(metric: dict) -> bool:
         return "workloads" not in metric or name in metric["workloads"]
@@ -90,13 +103,37 @@ def load_cell(name: str, benchmark: Optional[dict] = None) -> dict:
     return {
         "name": name,
         "chips": cell["chips"],
-        "config": json.loads((ROOT / configs[cell["config"]]["file"])
-                             .read_text()),
-        "mix": traffic.load(cell["traffic"]),
-        "limits": json.loads((BENCH / "limits" / f"{name}.json").read_text()),
+        "config": config,
+        "mix": traffic.load(cell["traffic"], data / "traffic"),
+        "limits": json.loads((data / "limits" / f"{name}.json").read_text()),
         "end_to_end": [m for m in benchmark["end_to_end"] if applies(m)],
         "per_layer": [m for m in benchmark["per_layer"] if applies(m)],
     }
+
+
+def load_arch(cfg: dict):
+    """The module ``bench/arch/<arch>.py`` of the architecture that the
+    configuration ``cfg`` names (``"arch"``). Loaded once per process, so
+    that the reference's compiled programs, keyed on its ``field``, are
+    reused."""
+    name = cfg.get("arch")
+    if not isinstance(name, str) \
+            or not re.fullmatch(r"[A-Za-z0-9_]+", name):
+        raise ValueError(f"configuration {cfg.get('name')!r} names no "
+                         f"architecture (\"arch\"): {name!r}")
+    key = f"bench_arch_{name}"
+    if key in sys.modules:
+        return sys.modules[key]
+    path = BENCH / "arch" / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"configuration {cfg.get('name')!r} names "
+                                f"the architecture {name!r}, which has no "
+                                f"module {path}")
+    spec = importlib.util.spec_from_file_location(key, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    sys.modules[key] = mod
+    return mod
 
 
 def metric_reader(name: str) -> Callable:
@@ -176,29 +213,20 @@ def render_config(cfg: dict):
         scene=cfg["scene"], res=cfg["res"], window=cfg["window"],
         num_slots=cfg["num_slots"], backend="streaming", fused_tick=True,
         pool_holes=True, pool_bucket=cfg["pool_bucket"],
-        ray_chunk=cfg["ray_chunk"], grid_res=cfg["grid_res"],
-        channels=cfg["channels"], num_samples=cfg["num_samples"],
-        decoder=cfg["decoder"],
-        stream_capacity=cfg["rit_capacity"]).resolved()
+        ray_chunk=cfg["ray_chunk"],
+        **load_arch(cfg).config_fields(cfg)).resolved()
 
 
 def build_engine(cfg: dict, weights: dict):
     """The serving engine exactly as ``repro.api`` builds it, over the
     benchmark's weights."""
     from repro import api
-    from repro.nerf import models
 
     rcfg = render_config(cfg)
     if rcfg.camera.focal <= 0 or cfg["fov_deg"] != 50.0:
         raise ValueError("the serving camera is the repository's 50-degree "
                          "square camera")
-    model, _ = models.make_model(
-        "dvgo", grid_res=cfg["grid_res"], channels=cfg["channels"],
-        decoder=cfg["decoder"], mlp_hidden=cfg["mlp_hidden"],
-        num_samples=cfg["num_samples"], near=cfg["near"], far=cfg["far"],
-        backend="streaming", stream_capacity=cfg["rit_capacity"],
-        pallas_interpret=rcfg.pallas_interpret)
-    params = {"table": weights["table"], "decoder": weights["decoder"]}
+    model, params = load_arch(cfg).build_model(cfg, rcfg, weights)
     renderer = api.make_renderer(rcfg, model=model, params=params)
     return renderer.pipeline.serve_engine_for(rcfg)
 
@@ -397,6 +425,7 @@ def compare(run: dict, viewers: Viewers, cfg: dict, weights: dict,
     over the co-rendered next reference frames."""
     import reference
 
+    arch = load_arch(cfg)
     cam = reference.Camera(cfg["res"], cfg["fov_deg"])
     refs = next_refs(run)
     picked = pick_windows(run["records"], count, seed)
@@ -405,7 +434,7 @@ def compare(run: dict, viewers: Viewers, cfg: dict, weights: dict,
     hole_gap = 0
     for rec in picked:
         poses = viewers.poses[rec["sid"]]
-        want = reference.render_window(weights, cfg, cam, poses,
+        want = reference.render_window(arch, weights, cfg, cam, poses,
                                        rec["start"], rec["count"], precision)
         if control is None:
             got_frames = rec["frames"]
@@ -417,7 +446,7 @@ def compare(run: dict, viewers: Viewers, cfg: dict, weights: dict,
                 got_ref = (np.asarray(rgb[s]).reshape(-1, 3),
                            np.asarray(dep[s]).reshape(-1))
         else:
-            got = reference.render_window(weights, cfg, cam, poses,
+            got = reference.render_window(arch, weights, cfg, cam, poses,
                                           rec["start"], rec["count"],
                                           control)
             got_frames, got_holes = got["frames"], got["hole_counts"]
@@ -490,6 +519,7 @@ def run_record(run: dict, cfg: dict, device: dict) -> dict:
     import peaks
     import work
 
+    arch = load_arch(cfg)
     hw = run["hw"]
     ns = cfg["num_samples"]
     bucket = cfg["pool_bucket"]
@@ -503,12 +533,12 @@ def run_record(run: dict, cfg: dict, device: dict) -> dict:
             "primed_rays": hw * len(t["admitted"]),
             "frames": t["frames_live"], "live_slots": live,
             "rit": t["rit"].tolist(),
-            "work": work.tick_work(cfg, hole_rays, ref_rays,
+            "work": work.tick_work(arch, cfg, hole_rays, ref_rays,
                                    t["frames_live"], live),
-            "prime_work": work.tick_work(cfg, 0, hw * len(t["admitted"]),
-                                         0, 0),
-            "gather_work": work.gather_work(cfg, (hole_rays + ref_rays) * ns),
-            "mlp_work": work.mlp_work(cfg, (hole_rays + ref_rays) * ns),
+            "prime_work": work.tick_work(arch, cfg, 0,
+                                         hw * len(t["admitted"]), 0, 0),
+            "gather_work": arch.gather_work(cfg, (hole_rays + ref_rays) * ns),
+            "mlp_work": arch.decoder_work(cfg, (hole_rays + ref_rays) * ns),
         })
     recs = [r for r in run["records"] if r["in_window"]]
     return {
@@ -539,14 +569,12 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *,
     ``"control_correct"``)."""
     import jax
 
-    import weights as weights_mod
-
     if require_chip:
         check_device(cell["chips"])
 
     cfg = cell["config"]
     clock = CompileClock()
-    weights = jax.block_until_ready(weights_mod.make_weights(cfg, seed))
+    weights = jax.block_until_ready(load_arch(cfg).make_weights(cfg, seed))
     engine = build_engine(cfg, weights)
     viewers = Viewers(cell["mix"], cfg["window"], seed)
     trace_dir = None

@@ -471,8 +471,6 @@ def main(argv=None) -> int:
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     import jax
 
-    import weights
-
     cell = run_cell.load_cell(args.workload)
     run_cell.enable_cache(jax)
     try:
@@ -483,7 +481,7 @@ def main(argv=None) -> int:
     cfg = cell["config"]
     clock = run_cell.CompileClock()
     engine = run_cell.build_engine(cfg, jax.block_until_ready(
-        weights.make_weights(cfg, args.seed)))
+        run_cell.load_arch(cfg).make_weights(cfg, args.seed)))
     viewers = run_cell.Viewers(cell["mix"], cfg["window"], args.seed)
     trace_dir = BENCH.parent / ".bench_trace" / f"{cell['name']}.stages"
     shutil.rmtree(trace_dir, ignore_errors=True)

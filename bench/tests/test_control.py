@@ -5,26 +5,23 @@ reads it on the chip at the cell's size, where it comes out not correct
 by the cell's own limits (``frame_err_p99`` about 6x its limit,
 ``hole_err_max`` about 3x). At this tiny size it reads less, but still
 fails them, and reads far above the program; its verdict is the
-harness's own judgement of its readings."""
+harness's own judgement of its readings. One case per tiny cell of every
+configuration in ``BENCHMARK.json`` and of the MLP decoder's."""
 from __future__ import annotations
 
 import pytest
 
-from bench_cells import tiny_cell
+from bench_cells import CONFIG_FILES, cell_id, cells, tiny_cell
+
+CELLS = cells(list(CONFIG_FILES))
 
 
-CELLS = {"baked.preview": ("cicero-dvgo-baked.preview", None),
-         "baked.steady": ("cicero-dvgo-baked.steady", None),
-         "baked.churn": ("cicero-dvgo-baked.churn", None),
-         "mlp.steady": ("cicero-dvgo-baked.steady",
-                        "configs/cicero-dvgo.json")}
-
-
-@pytest.mark.parametrize("cell", sorted(CELLS))
-def test_control_fails_where_the_program_passes(cell):
+@pytest.mark.parametrize("config,mix", CELLS,
+                         ids=[cell_id(*c) for c in CELLS])
+def test_control_fails_where_the_program_passes(config, mix):
     import run_cell
 
-    c = tiny_cell(*CELLS[cell])
+    c = tiny_cell(config, mix)
     r = run_cell.run(c, 2**31 + 3, 2.0, trace=False, require_chip=False,
                      control="high")
     program, control = r["readings"], r["control"]

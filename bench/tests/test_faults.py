@@ -1,12 +1,14 @@
 """The harness drives a run with the timed path broken underneath, and
 ``correct`` comes out false for each fault a serving cell can have. The
-chip check is skipped; the tiny cells run on the CPU with the cells'
-own limits."""
+chip check is skipped; the tiny cells (one per cell of every configuration
+in ``BENCHMARK.json``, ``bench_cells.cells``) run on the CPU with the
+cells' own limits."""
 from __future__ import annotations
 
 import pytest
 
-from bench_cells import tiny_cell
+from bench_cells import (BENCHMARK_CONFIGS, carries_state, cell_id, cells,
+                         tiny_cell)
 
 
 def alter_one_frame(engine, result):
@@ -57,32 +59,32 @@ def plant_hole_fault(monkeypatch):
     monkeypatch.setattr(raybatch, "scatter_segments", faulty)
 
 
-CELLS = ["cicero-dvgo-baked.steady", "cicero-dvgo-baked.churn",
-         "cicero-dvgo-baked.preview"]
+CELLS = cells(BENCHMARK_CONFIGS)
 
 
 # one-window sessions carry no state from one tick to the next
 CASES = [(cell, fault) for fault in sorted(FAULTS) for cell in CELLS
-         if not (cell.endswith(".preview") and fault == "state_unchanged")]
+         if fault != "state_unchanged" or carries_state(*cell)]
 
 
-@pytest.mark.parametrize("cell,fault", CASES)
+@pytest.mark.parametrize("cell,fault", CASES,
+                         ids=[f"{cell_id(*c)}-{f}" for c, f in CASES])
 def test_fault_is_not_correct(cell, fault):
     import run_cell
 
-    c = tiny_cell(cell)
+    c = tiny_cell(*cell)
     c["mix"]["check_windows"] = 6
     r = run_cell.run(c, 2**31 + 5, 2.0, trace=False,
                      require_chip=False, fault=FAULTS[fault]())
     assert r["correct"] is False, r["checks"]
 
 
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell", CELLS, ids=[cell_id(*c) for c in CELLS])
 def test_one_slots_hole_rays_altered_is_not_correct(cell, monkeypatch):
     import run_cell
 
     plant_hole_fault(monkeypatch)
-    c = tiny_cell(cell)
+    c = tiny_cell(*cell)
     c["mix"]["check_windows"] = 6
     # fast enough that 16-pixel frames warped from a reference two frames
     # away have holes (at the cells' 192 pixels 2% of pixels are holes)

@@ -23,10 +23,10 @@ def programs():
     import jax.numpy as jnp
 
     import run_cell
-    import weights
 
     cfg = tiny_cell()["config"]
-    engine = run_cell.build_engine(cfg, weights.make_weights(cfg, 7)).engine
+    weights = run_cell.load_arch(cfg).make_weights(cfg, 7)
+    engine = run_cell.build_engine(cfg, weights).engine
     s, n, r = cfg["num_slots"], cfg["window"], cfg["res"]
     eye = jnp.stack([jnp.eye(4)] * s)
     rgb, dep = jnp.zeros((s, r, r, 3)), jnp.zeros((s, r, r))

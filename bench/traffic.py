@@ -31,8 +31,8 @@ TRAFFIC_DIR = Path(__file__).resolve().parent / "traffic"
 SESSIONS_PER_VIEWER = 32
 
 
-def load(name: str) -> dict:
-    return json.loads((TRAFFIC_DIR / f"{name}.json").read_text())
+def load(name: str, directory: Path = TRAFFIC_DIR) -> dict:
+    return json.loads((directory / f"{name}.json").read_text())
 
 
 @dataclass

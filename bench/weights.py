@@ -1,18 +1,15 @@
-"""The benchmark's own weights: the baked scene table and the decoder.
+"""The benchmark's own scene, shared by every architecture.
 
-Both are made on the device in one jitted call from the configuration and
-the seed, in float32 as they are served. The scene is the analytic
-sphere-and-ground field of the named scene (seeded from the CRC32 of its
-name, as the repository's procedural scenes are), baked at the grid
-vertices into (sigma, r, g, b) and zero-padded to the configured channel
-count. The decoder weights, for an MLP configuration, are drawn from the
-run's seed. Nothing here is taken from the program under test; the plain
-reference reads these same arrays.
+The scene is the analytic sphere-and-ground field of the named scene
+(seeded from the CRC32 of its name, as the repository's procedural scenes
+are). An architecture module (``bench/arch/<arch>.py``) stores it in its
+model's layout, on the device in one jitted call from the configuration
+and the seed; nothing is taken from the program under test, and the plain
+reference reads those same arrays.
 """
 from __future__ import annotations
 
 import zlib
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -36,10 +33,10 @@ def scene_spheres(name: str, num_spheres: int = 6):
             albedos.astype(np.float32))
 
 
-def _bake(centers, radii, albedos, res: int, channels: int):
-    axes = jnp.linspace(-1.0, 1.0, res)
-    x, y, z = jnp.meshgrid(axes, axes, axes, indexing="ij")
-    p = jnp.stack([x, y, z], axis=-1).reshape(-1, 3)
+def scene_field(p, centers, radii, albedos):
+    """``(sigma [P], rgb [P, 3])`` of the scene at the points ``p [P, 3]``:
+    a density that rises across each surface, Lambert-shaded albedo with a
+    faint texture, nothing outside the unit cube."""
     d_sph = (jnp.linalg.norm(p[:, None, :] - centers[None], axis=-1)
              - radii[None])
     d_all = jnp.concatenate([d_sph, (p[:, 1] - GROUND)[:, None]], axis=1)
@@ -59,43 +56,10 @@ def _bake(centers, radii, albedos, res: int, channels: int):
                                      0.0, 1.0)
     tex = 0.9 + 0.1 * jnp.sin(9.0 * p[:, :1]) * jnp.cos(7.0 * p[:, 2:3])
     rgb = jnp.clip(albs[idx] * lambert * tex, 0.0, 1.0)
-    table = jnp.concatenate([sigma[:, None], rgb], axis=-1)
-    return jnp.pad(table, ((0, 0), (0, channels - 4)))
-
-
-def _decoder(key, channels: int, hidden: int, dir_dims: int = 9):
-    k1, k2, k3, k4 = jax.random.split(key, 4)
-
-    def normal(k, shape):
-        return jax.random.normal(k, shape, jnp.float32) / np.sqrt(shape[0])
-
-    return {
-        "w1": normal(k1, (channels, hidden)),
-        "b1": jnp.zeros((hidden,), jnp.float32),
-        "w2": normal(k2, (hidden, hidden)),
-        "b2": jnp.zeros((hidden,), jnp.float32),
-        "w_sigma": normal(k3, (hidden, 1)),
-        "w_rgb": normal(k4, (hidden + dir_dims, 3)),
-        "b_rgb": jnp.zeros((3,), jnp.float32),
-    }
+    return sigma, rgb
 
 
 def seed_key(seed: int) -> jax.Array:
     """A PRNG key from any non-negative seed, wider than 32 bits too."""
     return jax.random.fold_in(jax.random.PRNGKey(seed & 0xFFFFFFFF),
                               (seed >> 32) & 0xFFFFFFFF)
-
-
-@partial(jax.jit, static_argnames=("res", "channels", "hidden", "mlp"))
-def _make(centers, radii, albedos, key, *, res, channels, hidden, mlp):
-    out = {"table": _bake(centers, radii, albedos, res, channels)}
-    out["decoder"] = _decoder(key, channels, hidden) if mlp else {}
-    return out
-
-
-def make_weights(cfg: dict, seed: int) -> dict:
-    """``{"table": [res^3, C], "decoder": {...} or {}}`` on the device."""
-    centers, radii, albedos = scene_spheres(cfg["scene"])
-    return _make(centers, radii, albedos, seed_key(seed),
-                 res=cfg["grid_res"], channels=cfg["channels"],
-                 hidden=cfg["mlp_hidden"], mlp=cfg["decoder"] == "mlp")
