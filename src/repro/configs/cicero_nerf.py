@@ -19,8 +19,18 @@ class CiceroPipelineCfg:
 
 
 # full-scale configs (dry-run / cost-model scale: 800x800 frames, 192 samples)
+# DVGO's fine-grid widths (160^3 vertices, 12 feature channels) decoded by the
+# repository's own MLP(64): a density head on the hidden layer and a 9-wide
+# quadratic direction encoding. DVGO publishes no such decoder.
 DVGO = NerfConfig(kind="dvgo", grid_res=160, channels=12, decoder="mlp",
                   mlp_hidden=64, num_samples=192)
+# DVGO's published fine stage (arXiv 2111.11215, configs/default.py
+# fine_model_and_render): one table of the 160^3 raw density grid and the
+# 12-channel k0 grid, the density post-activated with alpha_init 1e-2, and
+# rgbnet 39 -> 128 -> 128 -> 3 over k0 and a 4-frequency view encoding
+DVGO_FINE = NerfConfig(kind="dvgo", grid_res=160, channels=13,
+                       decoder="rgbnet", mlp_hidden=128, viewbase_pe=4,
+                       alpha_init=1e-2, num_samples=192)
 NGP = NerfConfig(kind="ngp", hash_levels=8, hash_table_size=2**19,
                  hash_base_res=16, hash_max_res=1024, decoder="mlp",
                  mlp_hidden=64, num_samples=192)
@@ -40,6 +50,7 @@ TENSORF_BENCH = NerfConfig(kind="tensorf", grid_res=64, tensorf_rank=8,
 
 NERF_CONFIGS = {
     "cicero-dvgo": DVGO,
+    "cicero-dvgo-fine": DVGO_FINE,
     "cicero-ngp": NGP,
     "cicero-tensorf": TENSORF,
 }

@@ -153,10 +153,11 @@ class StreamingTickResult(NamedTuple):
     #                           split on the fused path)
     next_rgb_ref: jnp.ndarray  # [S, H, W, 3] — tick t+1's reference frames
     next_dep_ref: jnp.ndarray  # [S, H, W]
-    # [3, 2] int32 — rows 0 and 1 (hole stage, reference stage): (samples
+    # [4, 2] int32 — rows 0 and 1 (hole stage, reference stage): (samples
     # that spilled past the RIT, always 0 since the RIT is ragged; live
     # samples gathered), pooled padding rows not counted; row 2: (pad
-    # columns, columns) in the live blocks of the tick's merged sweep
+    # columns, columns) in the live blocks of the tick's merged sweep;
+    # row 3: (pooled padding rows decoded, rows decoded)
     rit_counts: jnp.ndarray
 
 
@@ -225,9 +226,10 @@ def render_tick_streaming(model, params: dict, cam: rays.Camera, *,
         num_seg=s, scene_of_seg=params.get("scene_of_seg"),
         interpret=c.pallas_interpret)
     sig_h, rgb_h = model.decode_features(
-        params, feats.hole, jnp.repeat(hole_batch.dirs, ns, axis=0))
+        params, feats.hole, jnp.repeat(hole_batch.dirs, ns, axis=0),
+        tick=True)
     sig_r, rgb_r = model.decode_features(
-        params, feats.ref, jnp.repeat(ref_batch.dirs, ns, axis=0))
+        params, feats.ref, jnp.repeat(ref_batch.dirs, ns, axis=0), tick=True)
     fill_col, _, _ = volrend.composite(sig_h.reshape(-1, ns),
                                        rgb_h.reshape(-1, ns, 3), t_h,
                                        c.far, c.white_bkgd)
@@ -240,12 +242,17 @@ def render_tick_streaming(model, params: dict, cam: rays.Camera, *,
                               s * n * hw).reshape(s, n, hw, 3)
     with jax.named_scope("rit_build"):
         # the ragged RIT spills nothing: rows 0 and 1 count live samples
-        # beside a spill of 0; row 2 is the sweep's padding
+        # beside a spill of 0; row 2 is the sweep's padding; row 3 the
+        # rows the decoder ran on, of which the pooled padding's are dead
         zero = jnp.zeros((), jnp.int32)
+        live_hole = jnp.sum(valid) * ns
+        decoded = feats.hole.shape[0] + feats.ref.shape[0]
         rit_counts = jnp.stack([
-            jnp.stack([zero, jnp.sum(valid) * ns]),
+            jnp.stack([zero, live_hole]),
             jnp.stack([zero, jnp.asarray(feats.ref.shape[0])]),
             jnp.stack([feats.pad_columns, feats.columns]),
+            jnp.stack([feats.hole.shape[0] - live_hole,
+                       jnp.asarray(decoded)]),
         ]).astype(jnp.int32)
     overflowed = frame_over | (totals > pool_caps)
     fill = sparse

@@ -1,17 +1,39 @@
-"""Pallas TPU kernel: fused radiance MLP (Feature Computation ``F``).
+"""Pallas TPU kernel: fused radiance decoder (Feature Computation ``F``).
 
 The paper's NPU keeps MLP weights in a dedicated 96 KB weight buffer and
-streams interpolated features through the MAC array. Here the whole 2-layer
-MLP + sigma/rgb heads run fused in VMEM: weights are block-resident for every
-grid step (they fit — 10–100 KB, §II-C), activations never round-trip to HBM.
+streams interpolated features through the MAC array. Here the whole decoder
+runs fused in VMEM: weights are block-resident for every grid step (they
+fit — 10–100 KB, §II-C), activations never round-trip to HBM. Two decoders
+share the kernel's name:
 
-  feats [S, C] , direnc [S, 9-padded-to-16]  →  out [S, 4] = (sigma, rgb)
+* the repository's MLP (``density=None``): a 2-layer MLP with sigma and rgb
+  heads, sample-major::
 
-Grid over sample blocks; MXU-aligned hidden width (default 64/128).
+    feats [S, C] , direnc [S, 9-padded-to-16]  →  out [S, 4] = (sigma, rgb)
+
+* DVGO's fine stage (``density=(shift, voxel_size)``): the raw density is
+  post-activated, ``softplus(density + shift) / voxel_size``, and rgbnet
+  (``[k0, pe(d)] → 128 → 128 → 3``, sigmoid) decodes the k0 features with
+  the view encoding ``pe(d) = [d, sin(d 2^k), cos(d 2^k)]``, computed here
+  from the 3-float direction. Channel-major, so that the sample axis is on
+  the 128 lanes and a 13-channel row is not padded to 128::
+
+    feats [C, S] (row 0 raw density, rows 1.. k0), dirs [3, S]
+        →  out [4, S] = (sigma, rgb)
+
+  rgbnet's first layer arrives split by its input rows (k0 with a zero
+  column for the density row, d, the sines, the cosines), so the 39-wide
+  input is never concatenated.
+
+Grid over sample blocks; MXU-aligned hidden width (default 64/128). The
+fused tick's decode runs as :func:`fused_nerf_mlp`; every staged render's
+(the admission prime's among them) as :func:`staged_nerf_mlp`, the same
+kernel under its own name so a profile tells the two apart.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -27,8 +49,8 @@ def _dot(a, b):
                        preferred_element_type=jnp.float32)
 
 
-def _kernel(x_ref, d_ref, w1_ref, b1_ref, w2_ref, b2_ref, ws_ref, wr_ref,
-            br_ref, out_ref):
+def _mlp_kernel(x_ref, d_ref, w1_ref, b1_ref, w2_ref, b2_ref, ws_ref, wr_ref,
+                br_ref, out_ref):
     x = x_ref[...]  # [blk, C]
     h = jnp.maximum(_dot(x, w1_ref[...]) + b1_ref[...], 0.0)
     h = jnp.maximum(_dot(h, w2_ref[...]) + b2_ref[...], 0.0)
@@ -38,29 +60,93 @@ def _kernel(x_ref, d_ref, w1_ref, b1_ref, w2_ref, b2_ref, ws_ref, wr_ref,
     out_ref[...] = jnp.concatenate([sigma, rgb], axis=-1).astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def fused_nerf_mlp(feats: jnp.ndarray, direnc: jnp.ndarray, w1, b1, w2, b2,
-                   w_sigma, w_rgb, b_rgb, *, block: int = 512,
-                   interpret: bool | None = None) -> jnp.ndarray:
-    """Returns [S, 4] = (sigma_raw_softplus, rgb_sigmoid). S must be a
-    multiple of ``block`` (ops.py pads)."""
-    interpret = resolve_interpret(interpret)
+def _rgbnet_kernel(x_ref, d_ref, w1k_ref, w1d_ref, w1s_ref, w1c_ref,
+                   freq_ref, b1_ref, w2_ref, b2_ref, w3_ref, b3_ref, out_ref,
+                   *, shift: float, voxel_size: float):
+    d = d_ref[...]  # [3, blk]
+    # [3 pe, blk]: d_a 2^k, the frequency fastest within each axis (the
+    # frequency matrix holds powers of two, so each product is exact)
+    z = _dot(freq_ref[...], d)
+    h = (_dot(w1k_ref[...], x_ref[...]) + _dot(w1d_ref[...], d)
+         + _dot(w1s_ref[...], jnp.sin(z)) + _dot(w1c_ref[...], jnp.cos(z))
+         + b1_ref[...])
+    h = jnp.maximum(h, 0.0)
+    h = jnp.maximum(_dot(w2_ref[...], h) + b2_ref[...], 0.0)
+    rgb = jax.nn.sigmoid(_dot(w3_ref[...], h) + b3_ref[...])
+    sigma = jax.nn.softplus(x_ref[0:1, :] + shift) / voxel_size
+    out_ref[0:1, :] = sigma.astype(out_ref.dtype)
+    out_ref[1:4, :] = rgb.astype(out_ref.dtype)
+
+
+def _full(shape):
+    return pl.BlockSpec(shape, lambda i: tuple(0 for _ in shape))
+
+
+def _mlp_call(feats, direnc, weights, block, interpret):
     s, c = feats.shape
     dd = direnc.shape[1]
-    h = w1.shape[1]
+    h = weights[0].shape[1]
     assert s % block == 0, (s, block)
-    grid = (s // block,)
-    full = lambda *shape: pl.BlockSpec(shape, lambda i: tuple(0 for _ in shape))
     return pl.pallas_call(
-        _kernel,
-        grid=grid,
+        _mlp_kernel,
+        grid=(s // block,),
         in_specs=[
             pl.BlockSpec((block, c), lambda i: (i, 0)),
             pl.BlockSpec((block, dd), lambda i: (i, 0)),
-            full(c, h), full(1, h), full(h, h), full(1, h), full(h, 1),
-            full(h + dd, 3), full(1, 3),
+            _full((c, h)), _full((1, h)), _full((h, h)), _full((1, h)),
+            _full((h, 1)), _full((h + dd, 3)), _full((1, 3)),
         ],
         out_specs=pl.BlockSpec((block, 4), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((s, 4), feats.dtype),
-        interpret=interpret,
-    )(feats, direnc, w1, b1, w2, b2, w_sigma, w_rgb, b_rgb)
+        interpret=resolve_interpret(interpret),
+    )(feats, direnc, *weights)
+
+
+def _rgbnet_call(feats, dirs, weights, density, block, interpret):
+    c, s = feats.shape
+    assert s % block == 0, (s, block)
+    shift, voxel_size = density
+    return pl.pallas_call(
+        functools.partial(_rgbnet_kernel, shift=shift, voxel_size=voxel_size),
+        grid=(s // block,),
+        in_specs=[pl.BlockSpec((c, block), lambda i: (0, i)),
+                  pl.BlockSpec((3, block), lambda i: (0, i))]
+        + [_full(w.shape) for w in weights],
+        out_specs=pl.BlockSpec((4, block), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((4, s), feats.dtype),
+        interpret=resolve_interpret(interpret),
+    )(feats, dirs, *weights)
+
+
+def _decode(feats, dirs, weights, block, interpret, density):
+    if density is None:
+        return _mlp_call(feats, dirs, weights, block, interpret)
+    return _rgbnet_call(feats, dirs, weights, density, block, interpret)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("block", "interpret", "density"))
+def fused_nerf_mlp(feats: jnp.ndarray, dirs: jnp.ndarray, *weights,
+                   block: int = 512, interpret: bool | None = None,
+                   density: Optional[Tuple[float, float]] = None
+                   ) -> jnp.ndarray:
+    """The fused tick's decoder. With ``density=None``, the repository's
+    MLP: ``dirs`` is the direction encoding, ``weights`` are ``(w1, b1, w2,
+    b2, w_sigma, w_rgb, b_rgb)`` with row-vector biases, and the result is
+    ``[S, 4]`` = (softplus sigma, sigmoid rgb). With ``density=(shift,
+    voxel_size)``, DVGO's fine stage, channel-major: ``feats [C, S]``,
+    ``dirs [3, S]``, ``weights`` as :func:`repro.kernels.ops.dvgo_rgbnet`
+    lays them out, and the result ``[4, S]``. ``S`` must be a multiple of
+    ``block`` (ops.py pads)."""
+    return _decode(feats, dirs, weights, block, interpret, density)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("block", "interpret", "density"))
+def staged_nerf_mlp(feats: jnp.ndarray, dirs: jnp.ndarray, *weights,
+                    block: int = 512, interpret: bool | None = None,
+                    density: Optional[Tuple[float, float]] = None
+                    ) -> jnp.ndarray:
+    """:func:`fused_nerf_mlp` for the staged renders (the admission prime
+    among them), under its own name."""
+    return _decode(feats, dirs, weights, block, interpret, density)

@@ -9,12 +9,14 @@ import functools
 from typing import Tuple
 
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import streaming
 from repro.kernels import flash_attention as _fa
 from repro.kernels import fused_nerf_mlp as _mlp
 from repro.kernels import gather_trilerp as _gt
 from repro.kernels import streaming_pipeline as _sp
+from repro.nerf import mlp
 from repro.utils import round_up
 
 
@@ -76,20 +78,54 @@ def gather_features_streaming(table: jnp.ndarray, points: jnp.ndarray,
 
 
 def nerf_mlp(feats: jnp.ndarray, direnc: jnp.ndarray, params: dict, *,
-             block: int = 256, interpret: bool | None = None
-             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+             block: int = 256, interpret: bool | None = None,
+             tick: bool = False) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Fused decoder. params = repro.nerf.mlp decoder params (mode='mlp').
-    Returns (sigma [S], rgb [S,3])."""
+    Returns (sigma [S], rgb [S,3]). ``tick`` runs it as the fused tick's
+    kernel (:func:`repro.kernels.fused_nerf_mlp.fused_nerf_mlp`), otherwise
+    as the staged renders' (``staged_nerf_mlp``)."""
     s = feats.shape[0]
     s_pad = round_up(max(s, 1), block)
     fp = jnp.pad(feats, ((0, s_pad - s), (0, 0)))
     dp = jnp.pad(direnc, ((0, s_pad - s), (0, 0)))
-    out = _mlp.fused_nerf_mlp(
+    kernel = _mlp.fused_nerf_mlp if tick else _mlp.staged_nerf_mlp
+    out = kernel(
         fp, dp, params["w1"], params["b1"][None, :], params["w2"],
         params["b2"][None, :], params["w_sigma"], params["w_rgb"],
         params["b_rgb"][None, :], block=block, interpret=interpret)
     out = out[:s]
     return out[:, 0], out[:, 1:4]
+
+
+def dvgo_rgbnet(feats: jnp.ndarray, dirs: jnp.ndarray, params: dict,
+                cfg: "mlp.DecoderCfg", *, block: int = 1024,
+                interpret: bool | None = None, tick: bool = False
+                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """DVGO's fine-stage decoder (``repro.nerf.mlp`` mode ``rgbnet``)
+    through the fused kernel. ``feats`` [S, C] hold the raw density
+    (channel 0) and the k0 features, ``dirs`` [S, 3] the unit view
+    directions. Returns (sigma [S] in world units, rgb [S, 3]). The kernel
+    reads both channel-major, samples on lanes; rgbnet's first layer is
+    split by its input rows (k0, d, sines, cosines). ``tick`` as in
+    :func:`nerf_mlp`."""
+    s, c = feats.shape
+    k, pe = c - 1, cfg.viewbase_pe
+    s_pad = round_up(max(s, 1), block)
+    x = jnp.pad(feats, ((0, s_pad - s), (0, 0))).T
+    d = jnp.pad(dirs, ((0, s_pad - s), (0, 0))).T
+    w1 = params["w1"]
+    # row a*pe + k of the frequency matrix picks axis a at 2^k
+    freq = np.kron(np.eye(3), 2.0 ** np.arange(pe)[:, None])
+    weights = (
+        jnp.pad(w1[:k].T, ((0, 0), (1, 0))),  # the density row feeds none
+        w1[k:k + 3].T, w1[k + 3:k + 3 + 3 * pe].T, w1[k + 3 + 3 * pe:].T,
+        jnp.asarray(freq, feats.dtype), params["b1"][:, None],
+        params["w2"].T, params["b2"][:, None],
+        params["w3"].T, params["b3"][:, None])
+    kernel = _mlp.fused_nerf_mlp if tick else _mlp.staged_nerf_mlp
+    out = kernel(x, d, *weights, block=block, interpret=interpret,
+                 density=(cfg.density_shift, cfg.voxel_size))
+    return out[0, :s], out[1:4, :s].T
 
 
 # ---------------------------------------------------------------------------

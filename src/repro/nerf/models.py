@@ -38,8 +38,13 @@ class NerfConfig:
     hash_base_res: int = 16
     hash_max_res: int = 256
     tensorf_rank: int = 8
-    decoder: str = "mlp"  # mlp | direct
-    mlp_hidden: int = 64
+    decoder: str = "mlp"  # mlp | rgbnet | direct
+    mlp_hidden: int = 64  # the decoder's hidden width (rgbnet: 128)
+    # rgbnet (DVGO's fine stage; channel 0 is the raw density, the rest
+    # k0): view-encoding frequencies, and the alpha of one voxel step at
+    # raw density 0
+    viewbase_pe: int = 4
+    alpha_init: float = 1e-2
     num_samples: int = 64
     near: float = 0.5
     far: float = 6.0
@@ -82,7 +87,10 @@ class NerfConfig:
     @property
     def decoder_cfg(self) -> mlp.DecoderCfg:
         return mlp.DecoderCfg(mode=self.decoder, in_channels=self.feat_channels,
-                              hidden=self.mlp_hidden)
+                              hidden=self.mlp_hidden,
+                              viewbase_pe=self.viewbase_pe,
+                              alpha_init=self.alpha_init,
+                              voxel_size=2.0 / (self.grid_res - 1))
 
     def feature_table_bytes(self) -> int:
         """Model size (the paper's Fig. 2 x-axis): feature vectors only."""
@@ -236,17 +244,26 @@ class NerfModel:
 
     @jax.named_scope("decode")
     def decode_features(self, params: dict, feats: jnp.ndarray,
-                        dirs: jnp.ndarray, backend: Optional[str] = None
+                        dirs: jnp.ndarray, backend: Optional[str] = None,
+                        tick: bool = False
                         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """Decoder tail of :meth:`query_field` — gathered features →
         (sigma, rgb). Split out so the unified streaming tick
         (``raybatch.render_tick_streaming``) can run its ONE fused gather
-        and still share the exact decoder path with the staged pipeline."""
+        and still share the exact decoder path with the staged pipeline.
+        ``tick`` marks the fused tick's call, whose decoder kernel runs
+        under its own name (``fused_nerf_mlp``; staged renders run
+        ``staged_nerf_mlp``)."""
         backend = backend or self.cfg.backend
-        if backend == "streaming" and self.cfg.decoder == "mlp":
+        if backend == "streaming" and self.cfg.decoder in ("mlp", "rgbnet"):
             from repro.kernels import ops
 
+            if self.cfg.decoder == "rgbnet":
+                return ops.dvgo_rgbnet(feats, dirs, params["decoder"],
+                                       self.cfg.decoder_cfg, tick=tick,
+                                       interpret=self.cfg.pallas_interpret)
             return ops.nerf_mlp(feats, mlp._dir_enc(dirs), params["decoder"],
+                                tick=tick,
                                 interpret=self.cfg.pallas_interpret)
         return mlp.decode(params["decoder"], feats, dirs, self.cfg.decoder_cfg)
 

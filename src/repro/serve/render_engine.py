@@ -308,8 +308,9 @@ class RenderServeEngine:
         self._num_admission_ticks = 0  # ticks that ran a prime dispatch
         # fused ticks' RIT counters over every finalized tick (engine
         # lifetime): [stage (hole, ref), (spilled, gathered)] samples, then
-        # (pad columns, columns) of the ragged sweeps
-        self._rit_counts = np.zeros((3, 2), np.int64)
+        # (pad columns, columns) of the ragged sweeps, then (padding rows,
+        # rows) the decoder ran on
+        self._rit_counts = np.zeros((4, 2), np.int64)
 
     # ------------------------------------------------------------------
     def _effective(self, sess: RenderSession) -> Tuple[int, int]:
@@ -916,6 +917,9 @@ class RenderServeEngine:
             rit_metrics["pad"] = {
                 "pad_columns": int(rit[2, 0]), "columns": int(rit[2, 1]),
                 "pad_share": float(rit[2, 0] / max(rit[2, 1], 1))}
+            rit_metrics["decode"] = {
+                "pad_rows": int(rit[3, 0]), "rows": int(rit[3, 1]),
+                "pad_share": float(rit[3, 0] / max(rit[3, 1], 1))}
         return {
             "ticks": self.num_ticks - start_ticks,
             "compiles": probe.recompiles(),

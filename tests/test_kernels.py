@@ -98,6 +98,67 @@ def test_fused_mlp_matches_decoder_path():
     np.testing.assert_allclose(np.asarray(rgb_k), np.asarray(rgb_m), atol=2e-5)
 
 
+def _plain_rgbnet(params, feats, dirs, pe, shift, voxel):
+    """DVGO's fine-stage decoder written out row by row: the view encoding
+    built entry by entry in DVGO's order (d, then the sines and the
+    cosines, each axis by axis with the frequency fastest), rgbnet over
+    ``[k0, pe]``, and the post-activated density."""
+    d = np.asarray(dirs, np.float64)
+    sines = [np.sin(d[:, a] * 2.0 ** k) for a in range(3) for k in range(pe)]
+    coses = [np.cos(d[:, a] * 2.0 ** k) for a in range(3) for k in range(pe)]
+    enc = np.stack([d[:, 0], d[:, 1], d[:, 2], *sines, *coses], axis=-1)
+    f = np.asarray(feats, np.float64)
+    w = {k: np.asarray(v, np.float64) for k, v in params.items()}
+    x = np.concatenate([f[:, 1:], enc], axis=-1)
+    h = np.maximum(x @ w["w1"] + w["b1"], 0.0)
+    h = np.maximum(h @ w["w2"] + w["b2"], 0.0)
+    rgb = 1.0 / (1.0 + np.exp(-(h @ w["w3"] + w["b3"])))
+    sigma = np.logaddexp(f[:, 0] + shift, 0.0) / voxel
+    return sigma, rgb
+
+
+@pytest.mark.parametrize("n,channels,hidden,pe,block", [
+    (3000, 13, 128, 4, 1024),   # the published widths, a padded last block
+    (700, 5, 32, 2, 256),
+    (256, 9, 64, 3, 128),
+])
+def test_rgbnet_kernel_matches_plain_rgbnet(n, channels, hidden, pe, block):
+    dcfg = mlp.DecoderCfg(mode="rgbnet", in_channels=channels, hidden=hidden,
+                          viewbase_pe=pe, voxel_size=2.0 / 159)
+    params = mlp.decoder_init(jax.random.key(n), dcfg)
+    params = {k: v + 0.05 * jax.random.normal(jax.random.key(7), v.shape)
+              if k.startswith("b") else v for k, v in params.items()}
+    feats = 4.0 * jax.random.normal(jax.random.key(2), (n, channels))
+    dirs = jax.random.normal(jax.random.key(3), (n, 3))
+    dirs = dirs / jnp.linalg.norm(dirs, axis=-1, keepdims=True)
+    want_sigma, want_rgb = _plain_rgbnet(params, feats, dirs, pe,
+                                         dcfg.density_shift, dcfg.voxel_size)
+    for tick in (False, True):
+        sig, rgb = ops.dvgo_rgbnet(feats, dirs, params, dcfg, block=block,
+                                   tick=tick)
+        assert sig.shape == (n,) and rgb.shape == (n, 3)
+        np.testing.assert_allclose(np.asarray(sig), want_sigma, rtol=2e-6,
+                                   atol=1e-6)
+        np.testing.assert_allclose(np.asarray(rgb), want_rgb, atol=2e-6)
+    # the program's plain decoder (the reference backend) agrees too
+    sig_m, rgb_m = mlp.decode(params, feats, dirs, dcfg)
+    np.testing.assert_allclose(np.asarray(sig_m), want_sigma, rtol=2e-6,
+                               atol=1e-6)
+    np.testing.assert_allclose(np.asarray(rgb_m), want_rgb, atol=2e-6)
+
+
+def test_rgbnet_shift_gives_alpha_init_at_zero_density():
+    """DVGO's ``act_shift``: a raw density of 0 gives ``alpha_init`` over
+    one voxel of travel."""
+    dcfg = mlp.DecoderCfg(mode="rgbnet", in_channels=13, hidden=128,
+                          alpha_init=1e-2, voxel_size=2.0 / 159)
+    sigma = mlp.density_activation(jnp.zeros((1,)), dcfg)
+    alpha = 1.0 - jnp.exp(-sigma * dcfg.voxel_size)
+    np.testing.assert_allclose(np.asarray(alpha), [1e-2], rtol=1e-5)
+    assert dcfg.pe_dims == 27 and dcfg.rgbnet_in == 39
+    assert mlp.decoder_flops(dcfg) == 43520
+
+
 # ---------------------------------------------------------------------------
 # flash attention (LM hot-spot)
 # ---------------------------------------------------------------------------

@@ -1,4 +1,7 @@
-"""Per-arch reduced smokes (all 10 assigned archs) + mixer equivalences."""
+"""Per-arch reduced smokes (all 10 assigned archs) + mixer equivalences, and
+DVGO's fine stage against the benchmark's plain reference."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,6 +10,7 @@ import pytest
 from repro.configs import registry
 from repro.configs.base import LayerSpec, ModelConfig
 from repro.models import lm, mamba, moe, xlstm
+from repro.nerf import models
 from repro.optim import adamw_init
 
 
@@ -171,3 +175,101 @@ def test_local_attention_is_banded():
     np.testing.assert_allclose(np.asarray(y1[:, -1]), np.asarray(y2[:, -1]),
                                atol=1e-5)  # last token unaffected
     assert float(jnp.abs(y1[:, 10] - y2[:, 10]).max()) > 1e-3
+
+
+# ---------------------------------------------------------------------------
+# DVGO's fine stage against the benchmark's plain reference of it
+# ---------------------------------------------------------------------------
+
+
+def _bench_fine(**over):
+    """The benchmark's ``dvgo-fine`` configuration at its ``TINY`` size
+    (with ``over``), its architecture module (``bench/arch/dvgo_fine.py``,
+    plain ``jax.numpy`` that imports nothing of the program) and the
+    harness."""
+    import json
+    import sys
+    from pathlib import Path
+
+    bench = Path(__file__).resolve().parents[1] / "bench"
+    if str(bench) not in sys.path:
+        sys.path.insert(0, str(bench))
+    import run_cell
+
+    cfg = json.loads((bench / "configs" / "dvgo-fine.json").read_text())
+    arch = run_cell.load_arch(cfg)
+    cfg.update(arch.TINY, **over)
+    return cfg, arch, run_cell
+
+
+@pytest.mark.parametrize("backend", ["reference", "streaming"])
+def test_dvgo_fine_query_field_matches_the_plain_reference(backend):
+    """The program's fine-stage model (post-activated density channel,
+    rgbnet over k0 and the view encoding) against the reference's field,
+    on seeded weights: the scene's density baked into the raw channel,
+    k0 N(0, 1), rgbnet N(0, 1/fan-in) with biases moved off zero."""
+    cfg, arch, run_cell = _bench_fine()
+    weights = arch.make_weights(cfg, 2**33 + 5)
+    weights["decoder"] = {
+        k: v + (0.1 if k.startswith("b") else 0.0)
+        for k, v in weights["decoder"].items()}
+    rcfg = run_cell.render_config(cfg)
+    model, params = arch.build_model(cfg, rcfg, weights)
+    if backend == "reference":
+        model = models.NerfModel(dataclasses.replace(model.cfg,
+                                                     backend="reference"))
+    params = model.prepare_streaming(params)
+    n = 2048
+    pts = jax.random.uniform(jax.random.key(4), (n, 3), minval=-1.0,
+                             maxval=1.0)
+    dirs = jax.random.normal(jax.random.key(5), (n, 3))
+    dirs = dirs / jnp.linalg.norm(dirs, axis=-1, keepdims=True)
+    sigma, rgb = model.query_field(params, pts, dirs)
+    want_sigma, want_rgb = arch.field(weights, pts, dirs,
+                                      arch.reference_key(cfg), "highest")
+    assert float(want_sigma.max()) > 10.0 and float(want_sigma.min()) >= 0.0
+    np.testing.assert_allclose(np.asarray(sigma), np.asarray(want_sigma),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(rgb), np.asarray(want_rgb),
+                               atol=1e-5)
+
+
+def test_dvgo_fine_fused_tick_matches_the_reference():
+    """One session of 4 frames served by the fused tick of the fine-stage
+    model, built as the benchmark builds it (``repro.api`` ->
+    ``RenderServeEngine``, streaming backend, pooled holes), against the
+    plain reference's render of the same window; and the tick's decode
+    counters: every row of the pooled hole bucket and of the co-rendered
+    references decoded, the bucket's rows past the hole total as
+    padding."""
+    import traffic
+    from repro.serve.render_engine import RenderSession
+
+    import reference
+
+    cfg, arch, run_cell = _bench_fine()
+    weights = arch.make_weights(cfg, 2**31 + 9)
+    engine = run_cell.build_engine(cfg, weights)
+    mix = traffic.load("preview")
+    mix["motion"]["step_deg"] = [3.0, 3.0]  # holes at 16x16 pixels
+    spec = traffic.sessions(mix, cfg["window"], 7)[0]
+    poses = traffic.orbit_poses(spec, mix["motion"])
+    sess = RenderSession(sid=0, poses=list(poses))
+    metrics = engine.run([sess])
+    got = np.stack([np.asarray(f) for f in sess.frames]).reshape(
+        len(poses), -1, 3)
+    cam = reference.Camera(cfg["res"], cfg["fov_deg"])
+    want = reference.render_window(arch, weights, cfg, cam, poses, 0,
+                                   len(poses), "highest")
+    assert sum(want["hole_counts"]) > 0
+    err = np.abs(got - want["frames"])
+    assert float(np.percentile(err, 99)) < 1e-5
+    assert float(err.mean()) < 1e-6
+    dec = metrics["rit_overflow"]["decode"]
+    hw, ns = cfg["res"] ** 2, cfg["num_samples"]
+    slots, bucket = cfg["num_slots"], cfg["pool_bucket"]
+    ticks = metrics["ticks"]
+    assert dec["rows"] == ticks * slots * (bucket + hw) * ns
+    live_holes = metrics["rit_overflow"]["hole"]["samples"]
+    assert dec["pad_rows"] == ticks * slots * bucket * ns - live_holes
+    assert 0.0 < dec["pad_share"] < 1.0

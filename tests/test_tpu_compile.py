@@ -6,7 +6,9 @@ Pallas kernel with ``interpret=False`` for one chip of a described
 20^3 MVoxels of 729 halo rows, 12 channels) and at the ragged RIT's
 static block counts for the preview cell's streams (two slots of 192x192
 frames, 192 samples per ray, a 16384-ray pool bucket and prime chunk,
-512 columns per block). Each test checks that the Mosaic kernel is in the
+512 columns per block), and the fused decoder at the repository's MLP(64)
+and at DVGO's fine stage (13 channels, rgbnet 39 -> 128 -> 128 -> 3, over
+the tick's reference stream). Each test checks that the Mosaic kernel is in the
 compiled program and that its arguments plus temporaries fit one v5e's
 HBM; the ragged sweeps also check their blocks against the kernels'
 scoped VMEM limit and their scalar-prefetched keys against the core's
@@ -35,6 +37,7 @@ TICK_SAMPLES = SLOTS * (POOL + RES * RES) * SAMPLES      # 20.4M
 PRIME_SAMPLES = POOL * SAMPLES                           # 3.1M a chunk
 PAGES = 2                   # a mixed-scene tick's stacked resident set
 HIDDEN, DIRENC = 64, 9
+FINE_CHANNELS = 13          # DVGO's fine stage: raw density and 12 k0
 HBM_BYTES = 15.75e9         # one v5e as the compiler counts it
 
 # kernel case -> (wrapper, samples in its stream, table pages): a prime
@@ -100,6 +103,8 @@ def _kernel_call(case, sds):
         fn = _wrapper(name)
         return (lambda *a: fn(*a, interpret=False)), _ragged_args(
             samples, pages, sds)
+    if case == "fused_nerf_mlp_rgbnet":
+        return _rgbnet_call(sds)
     assert case == "fused_nerf_mlp"
     s, h, d = BLOCK * 64, HIDDEN, DIRENC
     return (lambda *a: fused_nerf_mlp.fused_nerf_mlp(
@@ -109,7 +114,32 @@ def _kernel_call(case, sds):
         sds((h, 1), f32), sds((h + d, 3), f32), sds((1, 3), f32))
 
 
-@pytest.mark.parametrize("case", [*RAGGED, "fused_nerf_mlp"])
+def _rgbnet_call(sds):
+    """DVGO's fine-stage decoder at its published widths (13 channels:
+    raw density and 12 k0; rgbnet 39 -> 128 -> 128 -> 3, 4 view-encoding
+    frequencies) over the tick's reference stream, through the ops
+    wrapper the model calls (channel-major operands, the split first
+    layer)."""
+    from repro.kernels import ops
+    from repro.nerf import mlp
+
+    dcfg = mlp.DecoderCfg(mode="rgbnet", in_channels=FINE_CHANNELS,
+                          hidden=128, viewbase_pe=4,
+                          voxel_size=2.0 / (GRID - 1))
+    widths = (dcfg.rgbnet_in, 128, 128, 3)
+    params = {}
+    for i, (a, b) in enumerate(zip(widths[:-1], widths[1:]), start=1):
+        params[f"w{i}"] = sds((a, b), jnp.float32)
+        params[f"b{i}"] = sds((b,), jnp.float32)
+    s = SLOTS * RES * RES * SAMPLES
+    return (lambda f, d, p: ops.dvgo_rgbnet(f, d, p, dcfg, tick=True,
+                                            interpret=False),
+            (sds((s, FINE_CHANNELS), jnp.float32),
+             sds((s, 3), jnp.float32), params))
+
+
+@pytest.mark.parametrize("case", [*RAGGED, "fused_nerf_mlp",
+                                  "fused_nerf_mlp_rgbnet"])
 def test_kernel_compiles_for_v5e_and_fits(case, one_chip,
                                           no_persistent_cache):
     def sds(shape, dtype):
@@ -161,7 +191,7 @@ def _assert_keeps_its_name(case, scope, one_chip):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     fn, args = _kernel_call(case, sds)
-    name = RAGGED[case][0] if case in RAGGED else case
+    name = RAGGED[case][0] if case in RAGGED else "fused_nerf_mlp"
 
     def scoped(*a):
         with jax.named_scope(scope):
@@ -182,3 +212,10 @@ def test_mlp_kernel_keeps_its_name_under_the_decode_scope(
         one_chip, no_persistent_cache):
     # NerfModel.decode_features opens ``decode`` around ops.nerf_mlp
     _assert_keeps_its_name("fused_nerf_mlp", "decode", one_chip)
+
+
+def test_rgbnet_kernel_keeps_its_name_under_the_decode_scope(
+        one_chip, no_persistent_cache):
+    # ... and around ops.dvgo_rgbnet, whose fused tick's call the
+    # ``fused_nerf_mlp_roofline`` reader reads
+    _assert_keeps_its_name("fused_nerf_mlp_rgbnet", "decode", one_chip)
